@@ -56,6 +56,7 @@ tests/examples at smoke scale and is the TPU serving configuration.
 from __future__ import annotations
 
 import dataclasses
+import math
 import types
 import warnings
 from typing import Dict, List, Mapping, NamedTuple, Optional, Tuple
@@ -92,11 +93,11 @@ class PackedLinear:
     variant tag and shape metadata are static aux data, preserved by
     stacking/slicing and checked for equality by tree operations.
 
-    sparse_vals : (D_out, D_in) dense-masked W_S, or (D_out, D_in/m, n)
+    sparse_vals : (D_out, D_in) dense-masked W_S, or (n, D_in/m, D_out)
                   N:M values, or (D_out, K_max) ELL values, or None.
-    sparse_idx  : (D_out, D_in/m, n) int8 N:M positions, or
+    sparse_idx  : (n, D_in/m, D_out) int8 N:M positions, or
                   (D_out, K_max) uint16 ELL column ids, or None.
-    b_packed    : (D_out, D_in/32) uint32 sign bits, or None.
+    b_packed    : (D_in/32, D_out) uint32 sign bits, or None.
     u, v        : (D_out, r) / (D_in, r) low-rank factors, or None.
     """
 
@@ -350,11 +351,12 @@ def packed_linear_axes(pl: PackedLinear, stacked: bool = False,
     """The logical-axes tree of one packed linear: a PackedLinear with
     IDENTICAL static aux whose children are axes tuples, so it pairs
     structurally against the array tree in ``Planner.tree_specs`` /
-    ``jax.tree.map``. Every stored plane except ``v`` leads with d_out
-    — N:M values/indices ``(D_out, D_in/m, n)``, ELL planes ``(D_out,
-    K_max)``, dense-masked values ``(D_out, D_in)``, sign bits
-    ``(D_out, D_in/32)``, ``u (D_out, r)`` — so tensor parallelism is
-    uniform row sharding on ``"packed_out"`` (-> "model"). N:M groups
+    ``jax.tree.map``. Every stored plane except ``v`` carries d_out —
+    leading in ELL planes ``(D_out, K_max)``, dense-masked values
+    ``(D_out, D_in)`` and ``u (D_out, r)``, trailing (the lane axis the
+    kernels tile) in N:M values/indices ``(n, D_in/m, D_out)`` and sign
+    bits ``(D_in/32, D_out)`` — so tensor parallelism is uniform d_out
+    sharding on ``"packed_out"`` (-> "model"). N:M groups, sign words
     and ELL rows run along d_in and are never split by a d_out shard;
     a d_out that doesn't divide the mesh replicates via the planner's
     standard divisibility fallback (degraded-but-correct). ``u`` only
@@ -367,18 +369,31 @@ def packed_linear_axes(pl: PackedLinear, stacked: bool = False,
     doesn't divide the mesh."""
     lead = _lead if _lead is not None else (("layers",) if stacked else ())
 
-    def ax(a, row_sharded=True):
-        if a is None:
-            return None
-        nd = a.ndim - len(lead)
-        first = "packed_out" if row_sharded else None
-        return lead + (first,) + (None,) * (nd - 1)
-
     return PackedLinear(
-        ax(pl.sparse_vals), ax(pl.sparse_idx), ax(pl.b_packed),
-        ax(pl.u, pl.rank >= lr_shard_rank), ax(pl.v, False),
+        *_plane_axes(pl, lead, lr_shard_rank),
         variant=pl.variant, m_pat=pl.m_pat, d_in=pl.d_in,
         d_out=pl.d_out, rank=pl.rank)
+
+
+def _plane_axes(pl: PackedLinear, lead: Tuple, lr_shard_rank: int
+                ) -> Tuple:
+    """Per-plane axes tuples in PackedLinear child order; ``lead`` is
+    prepended (layer / expert stacking dims). D_out is the last dim of
+    N:M and sign-bit planes and the first of the others."""
+    d_out_at = -1 if pl.variant.endswith("-nm") else 0
+
+    def ax(a, out_dim):
+        if a is None:
+            return None
+        spec = [None] * (a.ndim - len(lead))
+        if out_dim is not None:
+            spec[out_dim] = "packed_out"
+        return lead + tuple(spec)
+
+    return (ax(pl.sparse_vals, d_out_at), ax(pl.sparse_idx, d_out_at),
+            ax(pl.b_packed, -1),
+            ax(pl.u, 0 if pl.rank >= lr_shard_rank else None),
+            ax(pl.v, None))
 
 
 def _expert_stack_depth(eps: ExpertPackedStack) -> int:
@@ -569,31 +584,69 @@ def _pick_block(dim: int, cap: int, mult: int = 1) -> int:
     return dim
 
 
-def _local_dim(dim: int) -> int:
-    """The per-shard extent of a "packed_out" dim under the ambient
-    mesh: block-size picking must see what one device actually holds,
-    or the kernel grid can't partition along the sharded rows (a block
-    spanning two shards forces GSPMD to gather the whole plane). Any
-    divisor of dim // n_model also divides dim, so the grid stays valid
-    for the global shape; without a mesh (or a non-dividing d_out,
-    which replicates) this is the identity and block choices are
-    byte-identical to the single-device path."""
-    from repro.runtime.meshctx import current_mesh
-    mesh = current_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
-        return dim
-    n = mesh.shape["model"]
-    return dim // n if (n > 1 and dim % n == 0) else dim
+def _out_rows(w: PackedLinear) -> int:
+    """The D_out extent the planes actually hold: the global d_out, or
+    one device's shard of it inside ``packed_matmul``'s shard_map (the
+    static ``d_out`` aux always names the global width)."""
+    if w.sparse_vals is not None:
+        return w.sparse_vals.shape[-1 if w.variant.endswith("-nm") else -2]
+    if w.b_packed is not None:
+        return w.b_packed.shape[-1]
+    return w.u.shape[-2]
+
+
+def _kernel_blocks(w: PackedLinear) -> Tuple[int, int]:
+    """(bn, bk) for the K-gridded kernels. Every streamed plane keeps
+    D_out on lanes, so bn is a multiple of 128 or the whole axis; bk
+    keeps the (bk/32, bn) sign-word tile in whole (8, 128) uint32 tiles
+    (bk % 256) and the (n, bk/m, bn) int8 index tile in whole (32, 128)
+    tiles (bk % 32m) — the tiling the chip's compiler demands."""
+    mult = 256 if w.b_packed is not None else 1
+    if w.m_pat:
+        mult = math.lcm(mult, 32 * w.m_pat)
+    return (_pick_block(_out_rows(w), 256, 128),
+            _pick_block(w.d_in, 1024, mult))
 
 
 def packed_matmul(x: Array, w: PackedLinear,
                   interpret: Optional[bool] = None) -> Array:
-    """x (..., D_in) @ Wᵀ through the variant's fused kernel."""
+    """x (..., D_in) @ Wᵀ through the variant's fused kernel.
+
+    Under a multi-device mesh the kernel runs inside ``shard_map`` (the
+    chip's compiler cannot partition a Pallas kernel): each device
+    streams its d_out shard of every plane and writes its slice of the
+    output features, which come back sharded on "model" — the packed
+    tensor-parallel layout. A d_out that doesn't divide the "model"
+    axis runs replicated (degraded-but-correct)."""
+    from repro.runtime.meshctx import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return _packed_matmul_local(x, w, interpret)
+    from jax.sharding import PartitionSpec as P
+    n_model = dict(mesh.shape).get("model", 1)
+    split = n_model > 1 and w.d_out % n_model == 0
+
+    def spec(ax):
+        return None if ax is None else P(*(
+            "model" if (a == "packed_out" and split) else None for a in ax))
+
+    w_specs = PackedLinear(*(spec(a) for a in _plane_axes(w, (), 0)),
+                           variant=w.variant, m_pat=w.m_pat, d_in=w.d_in,
+                           d_out=w.d_out, rank=w.rank)
+    out = P(*(None,) * (x.ndim - 1), "model" if split else None)
+    return jax.shard_map(
+        lambda xx, ww: _packed_matmul_local(xx, ww, interpret),
+        mesh=mesh, in_specs=(P(), w_specs), out_specs=out,
+        check_vma=False)(x, w)
+
+
+def _packed_matmul_local(x: Array, w: PackedLinear,
+                         interpret: Optional[bool]) -> Array:
     from repro.kernels import ops
     var = w.variant
+    bn, bk = _kernel_blocks(w)
     if var.endswith("-ell"):
-        kw = dict(bm=128, bn=_pick_block(_local_dim(w.d_out), 256),
-                  interpret=interpret)
+        kw = dict(bm=128, bn=bn, interpret=interpret)
         if var == "sparse-ell":
             y = ops.ell_matmul(x, w.sparse_vals, w.sparse_idx, **kw)
         elif var == "lowrank-ell":
@@ -603,9 +656,7 @@ def packed_matmul(x: Array, w: PackedLinear,
             y = ops.slab_ell_matmul(x, w.sparse_vals, w.sparse_idx,
                                     w.b_packed, w.u, w.v, **kw)
         return y.astype(x.dtype)
-    mult = (w.m_pat or 1) * (32 if (w.b_packed is not None) else 1)
-    kw = dict(bm=128, bn=_pick_block(_local_dim(w.d_out), 256),
-              bk=_pick_block(w.d_in, 1024, mult), interpret=interpret)
+    kw = dict(bm=128, bn=bn, bk=bk, interpret=interpret)
     if var == "slab-nm":
         y = ops.slab_nm_matmul(x, w.sparse_vals, w.sparse_idx, w.m_pat,
                                w.b_packed, w.u, w.v, **kw)
@@ -643,9 +694,9 @@ def packed_matmul_grouped(x: Array, w: PackedLinear,
     with the expert index leading the Pallas grid (kernels.grouped)."""
     from repro.kernels import ops
     var = w.variant
+    bn, bk = _kernel_blocks(w)
     if var.endswith("-ell"):
-        kw = dict(bm=128, bn=_pick_block(_local_dim(w.d_out), 256),
-                  interpret=interpret)
+        kw = dict(bm=128, bn=bn, interpret=interpret)
         if var == "sparse-ell":
             y = ops.ell_matmul_g(x, w.sparse_vals, w.sparse_idx, **kw)
         elif var == "lowrank-ell":
@@ -655,9 +706,7 @@ def packed_matmul_grouped(x: Array, w: PackedLinear,
             y = ops.slab_ell_matmul_g(x, w.sparse_vals, w.sparse_idx,
                                       w.b_packed, w.u, w.v, **kw)
         return y.astype(x.dtype)
-    mult = (w.m_pat or 1) * (32 if (w.b_packed is not None) else 1)
-    kw = dict(bm=128, bn=_pick_block(_local_dim(w.d_out), 256),
-              bk=_pick_block(w.d_in, 1024, mult), interpret=interpret)
+    kw = dict(bm=128, bn=bn, bk=bk, interpret=interpret)
     if var == "slab-nm":
         y = ops.slab_nm_matmul_g(x, w.sparse_vals, w.sparse_idx, w.m_pat,
                                  w.b_packed, w.u, w.v, **kw)
@@ -718,11 +767,6 @@ def expert_matmul(x: Array, w: ExpertPackedStack,
     return jnp.take(y, jnp.asarray(inv), axis=0)
 
 
-# q/k/v projections: output is a flat head*dh dim that the attention
-# layers immediately reshape per head — never constrain it flat.
-_FLAT_HEAD_TAPS = frozenset(("wq", "wk", "wv"))
-
-
 def linear(x: Array, w, tap: Optional[str] = None) -> Array:
     """Dispatch point used by the model layers: dense `x @ w` or the
     packed fused kernel. ``tap`` names this linear for activation
@@ -732,23 +776,7 @@ def linear(x: Array, w, tap: Optional[str] = None) -> Array:
     if tap is not None:
         tap_record(tap, x)
     if isinstance(w, PackedLinear):
-        from repro.runtime.meshctx import hint
-        y = packed_matmul(x, w)
-        if tap in _FLAT_HEAD_TAPS:
-            # q/k/v leave here flat (B, S, heads*dh) and are
-            # immediately re-laid-out per head; pinning the flat dim
-            # fights the head layout across the decode cache update
-            # and miscompiles under SPMD with the interpret-mode
-            # kernel (the mesh parity tests in tests/test_distributed
-            # caught real wrong logits) — leave them to propagation.
-            return y
-        # the packed-TP layout: every stored plane row-shards on d_out,
-        # so each device owns whole output rows and the result is
-        # "model"-sharded on its feature dim — one constraint per
-        # packed linear, mirroring the dense TP layout. hint() no-ops
-        # without a mesh and falls back when d_out doesn't divide
-        # (replicated degraded path).
-        return hint(y, *(None,) * (y.ndim - 1), "model")
+        return packed_matmul(x, w)
     return x @ w
 
 
@@ -803,7 +831,7 @@ def _describe(pl) -> str:
         return "experts[" + " | ".join(parts) + "]"
     d = pl.variant
     if pl.m_pat:
-        d += f"({pl.sparse_vals.shape[-1]}:{pl.m_pat})"
+        d += f"({pl.sparse_vals.shape[-3]}:{pl.m_pat})"
     elif pl.variant.endswith("-ell"):
         d += f"(kmax={pl.sparse_vals.shape[-1]})"
     if pl.rank:

@@ -1,10 +1,16 @@
 """Storage packing for SLaB components — the formats the Pallas kernels
 stream from HBM.
 
-- sign bits:   W_B {±1} -> uint32 words, 32 signs/word along D_in
-               (16x smaller than bf16; bit j of word g is column g*32+j).
-- N:M packed:  W_S (2:4 / 4:8) -> values (Do, Di*n/m) + int8 indices
+- sign bits:   W_B {±1} -> uint32 words (Di/32, Do), 32 signs/word
+               along D_in (16x smaller than bf16; bit j of word (g, o)
+               is W_B[o, g*32+j]).
+- N:M packed:  W_S (2:4 / 4:8) -> values (n, Di/m, Do) + int8 indices
                (position of each kept element inside its m-group).
+
+Every plane that the matmul kernels stream keeps D_out as its minor
+(lane) axis, so a kernel tile is (rows along D_in, bn along D_out) and
+the TPU's (8, 128) tiling never pads a short minor axis (a 2-wide slot
+axis or a D_in/32-wide word axis would).
 - ELL packed:  unstructured W_S -> row-padded values (Do, K_max) +
                uint16 column indices (uint32 when D_in > 65535),
                K_max = realized max per-row nnz (short rows pad with
@@ -23,34 +29,35 @@ Array = jax.Array
 # ------------------------------ sign bits ------------------------------
 
 def pack_sign_bits(w_b: Array) -> Array:
-    """Pack ±1 (or bool 'is positive') into uint32 along the last dim.
+    """Pack ±1 (or bool 'is positive') (Do, Di) into uint32 words
+    (Di/32, Do): bit j of word (g, o) is the sign of W_B[o, g*32+j].
 
     D_in must be a multiple of 32 (true for every assigned architecture).
     """
     d_out, d_in = w_b.shape
     if d_in % 32:
         raise ValueError(f"D_in={d_in} not a multiple of 32")
-    pos = (w_b > 0).astype(jnp.uint32).reshape(d_out, d_in // 32, 32)
+    pos = (w_b > 0).astype(jnp.uint32).T.reshape(d_in // 32, 32, d_out)
     shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(pos << shifts[None, None, :], axis=-1).astype(jnp.uint32)
+    return jnp.sum(pos << shifts[None, :, None], axis=1).astype(jnp.uint32)
 
 
 def unpack_sign_bits(packed: Array, d_in: int, dtype=jnp.int8) -> Array:
     """Inverse of pack_sign_bits: uint32 words -> ±1 matrix (Do, d_in)."""
-    d_out, words = packed.shape
+    words, d_out = packed.shape
     if words * 32 != d_in:
         raise ValueError(f"{words} words cannot hold D_in={d_in}")
     shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (packed[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
+    bits = (packed[:, None, :] >> shifts[None, :, None]) & jnp.uint32(1)
     pm = bits.astype(jnp.int32) * 2 - 1
-    return pm.reshape(d_out, d_in).astype(dtype)
+    return pm.reshape(d_in, d_out).T.astype(dtype)
 
 
 # ------------------------------ N:M packing ----------------------------
 
 class NMPacked(NamedTuple):
-    values: Array   # (Do, Di // m, n)
-    indices: Array  # (Do, Di // m, n) int8, position within the m-group
+    values: Array   # (n, Di // m, Do): slot s of group g of row o
+    indices: Array  # (n, Di // m, Do) int8, position within the m-group
     n: int
     m: int
     d_in: int
@@ -63,32 +70,41 @@ def pack_nm(w_s: Array, n: int, m: int, strict: bool = False) -> NMPacked:
     ``strict=True`` raises if any m-group holds MORE than n non-zeros
     (the pack would silently drop values) — the guard the plan-driven
     packer uses against a rule pattern that disagrees with what the
-    compressor actually produced."""
+    compressor actually produced.
+
+    Works on one (D_in/m, D_out) plane per position in the group, so
+    D_out stays the minor axis throughout (an (..., m) layout would pad
+    m to 128 lanes on a TPU). Slot order: a group's non-zeros by
+    position, then its zeros by position; slot s takes the position
+    whose rank in that order is s."""
     d_out, d_in = w_s.shape
     if d_in % m:
         raise ValueError(f"D_in={d_in} not divisible by m={m}")
-    g = w_s.reshape(d_out, d_in // m, m)
-    nz = (g != 0)
+    planes = w_s.reshape(d_out, d_in // m, m).transpose(2, 1, 0)
+    nz = [planes[p] != 0 for p in range(m)]
     if strict:
-        worst = int(jnp.max(jnp.sum(nz, axis=-1)))
+        worst = int(jnp.max(sum(z.astype(jnp.int32) for z in nz)))
         if worst > n:
             raise ValueError(
                 f"matrix is not {n}:{m} sparse (a group holds {worst} "
                 f"non-zeros; packing would drop values)")
-    # Order: non-zeros first (stable by position), then zeros.
-    order_key = jnp.where(nz, jnp.arange(m)[None, None, :], m + jnp.arange(m)[None, None, :])
-    idx = jnp.argsort(order_key, axis=-1)[..., :n].astype(jnp.int8)
-    vals = jnp.take_along_axis(g, idx.astype(jnp.int32), axis=-1)
-    return NMPacked(vals.astype(w_s.dtype), idx, n, m, d_in)
+    key = [jnp.where(nz[p], p, m + p) for p in range(m)]
+    rank = [sum((key[q] < key[p]).astype(jnp.int32)
+                for q in range(m) if q != p) for p in range(m)]
+    zero = jnp.zeros((), w_s.dtype)
+    vals = jnp.stack([sum(jnp.where(rank[p] == s, planes[p], zero)
+                          for p in range(m)) for s in range(n)])
+    idx = jnp.stack([sum(jnp.where(rank[p] == s, p, 0) for p in range(m))
+                     for s in range(n)]).astype(jnp.int8)
+    return NMPacked(vals, idx, n, m, d_in)
 
 
 def unpack_nm(p: NMPacked) -> Array:
-    d_out = p.values.shape[0]
-    rows = jnp.arange(d_out)[:, None, None]
-    grps = jnp.arange(p.d_in // p.m)[None, :, None]
-    g = jnp.zeros((d_out, p.d_in // p.m, p.m), p.values.dtype)
-    g = g.at[rows, grps, p.indices.astype(jnp.int32)].add(p.values)
-    return g.reshape(d_out, p.d_in)
+    planes = [sum(jnp.where(p.indices[s] == q, p.values[s],
+                            jnp.zeros((), p.values.dtype))
+                  for s in range(p.n)) for q in range(p.m)]
+    d_out = p.values.shape[-1]
+    return jnp.stack(planes).transpose(2, 1, 0).reshape(d_out, p.d_in)
 
 
 def nm_packed_bits(p: NMPacked, bits: int = 16) -> int:
@@ -159,7 +175,7 @@ class SLaBPacked(NamedTuple):
     sparse: NMPacked | ELLPacked | Array  # dense-masked fallback is a raw Array
     u: Array
     v: Array
-    b_packed: Array  # uint32 (Do, Di/32)
+    b_packed: Array  # uint32 (Di/32, Do)
     d_out: int
     d_in: int
 

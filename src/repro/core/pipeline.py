@@ -337,10 +337,16 @@ def _compress_leaf(layer: int, pth: str, w: Array, an: Optional[Array],
     err_b, err_a = _weighted_errs(w, w_new, an)
     cr = cl.cr if cl.cr is not None else comp.scfg.cr
     variant = ""
-    if cl.dec is not None:
+    dec = cl.dec
+    if dec is not None:
         from repro.core.packed_model import variant_of
-        variant = variant_of(cl.dec, r.scfg.pattern) or ""
-    return w_new, cl.dec, CompressStats(layer, pth, err_b, err_a, cr,
+        if dec.w_s is not None and dec.w_s.dtype != w.dtype:
+            # the sparse part is kept at the weight's own width: packing
+            # serves it at that width anyway, and f32 copies of every
+            # linear's W_S would double the memory held for packing
+            dec = dec._replace(w_s=dec.w_s.astype(w.dtype))
+        variant = variant_of(dec, r.scfg.pattern) or ""
+    return w_new, dec, CompressStats(layer, pth, err_b, err_a, cr,
                                         r.method, variant,
                                         cr_requested=float(r.scfg.cr))
 

@@ -24,10 +24,15 @@ def _exact_topk_mask_rows(scores2d: Array, k: int) -> Array:
         return jnp.zeros_like(scores2d, dtype=jnp.bool_)
     if k >= gsz:
         return jnp.ones_like(scores2d, dtype=jnp.bool_)
-    _, idx = jax.lax.top_k(scores2d, k)  # (n_groups, k)
-    rows = jnp.arange(n_groups, dtype=jnp.int32)[:, None]
-    mask = jnp.zeros(scores2d.shape, dtype=jnp.bool_)
-    return mask.at[rows, idx].set(True)
+    # the k-th largest value per row, then everything above it plus the
+    # lowest-index ties up to k — the set lax.top_k selects, built with
+    # compares and a cumsum (a scatter of the top-k indices needs
+    # several times the matrix in scratch at full model widths)
+    kth = jax.lax.top_k(scores2d, k)[0][:, -1:]
+    above = scores2d > kth
+    tie = scores2d == kth
+    room = k - jnp.sum(above, axis=1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=1) <= room))
 
 
 def group_topk_mask(scores: Array, keep_frac: float, group: Tuple[int, int] = (1, 0)) -> Array:
@@ -61,9 +66,17 @@ def nm_mask(scores: Array, n: int, m: int) -> Array:
     d_out, d_in = scores.shape
     if d_in % m:
         raise ValueError(f"D_in={d_in} not divisible by m={m}")
-    s = scores.reshape(d_out * (d_in // m), m)
-    mask = _exact_topk_mask_rows(s, n)
-    return mask.reshape(d_out, d_in)
+    # one (D_out, D_in/m) plane per position in the group, so the long
+    # axis stays minor: an (..., m) layout pads m to 128 lanes on a TPU.
+    # An element is kept when fewer than n of its group rank ahead of
+    # it (larger, or equal at a lower index) — lax.top_k's selection.
+    planes = scores.reshape(d_out, d_in // m, m).transpose(2, 0, 1)
+    keep = []
+    for p in range(m):
+        ahead = [(planes[q] > planes[p]) | ((planes[q] == planes[p]) & (q < p))
+                 for q in range(m) if q != p]
+        keep.append(sum(a.astype(jnp.int32) for a in ahead) < n)
+    return jnp.stack(keep).transpose(1, 2, 0).reshape(d_out, d_in)
 
 
 def parse_pattern(pattern: str) -> Tuple[int, int]:

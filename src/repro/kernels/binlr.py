@@ -8,14 +8,16 @@ generalization uses (U Vᵀ ⊙ B) x = Σ_r u_r ⊙ (B (v_r ⊙ x)): every rank
 term reuses the ONE streamed/expanded B tile, so extra ranks cost MXU
 passes but no extra HBM bytes beyond the (R·N + R·K) factor vectors.
 
-Grid is (M/bm, N/bn, K/bk); each step streams an (bn, bk/32) uint32
-tile, expands to ±1 in VMEM, and feeds the MXU. fp32 accumulation in
+Grid is (M/bm, N/bn, K/bk); each step streams a (bk/32, bn) uint32
+tile, expands it to the ±1 (bk, bn) tile of Bᵀ in VMEM, and feeds the
+MXU. fp32 accumulation in
 VMEM scratch; ``u_r`` is folded into each step's rank term (it is
 constant along K, so per-step scaling equals the end-scaling of the old
 rank-1 kernel).
 
-Block shapes: bm/bn/bk multiples of (8,128) tiles; bk multiple of 32·128
-keeps the packed tile lane-aligned (bk/32 lanes of uint32).
+Block shapes on the chip: bn a multiple of 128 (D_out is the lane axis
+of every streamed plane) and bk a multiple of 256, so the (bk/32, bn)
+word tile fills whole (8, 128) uint32 tiles — or the full axis.
 """
 from __future__ import annotations
 
@@ -51,10 +53,10 @@ def _kernel(x_ref, bp_ref, u_ref, v_ref, o_ref, acc_ref,
 def binlr_matmul(x: Array, b_packed: Array, u: Array, v: Array,
                  *, bm: int = 256, bn: int = 256, bk: int = 512,
                  interpret: bool = False) -> Array:
-    """x (M, K); b_packed (N, K/32) uint32; u (R, N); v (R, K) -> (M, N)."""
+    """x (M, K); b_packed (K/32, N) uint32; u (R, N); v (R, K) -> (M, N)."""
     m, k = x.shape
-    n = b_packed.shape[0]
-    assert b_packed.shape[1] * 32 == k, (b_packed.shape, k)
+    n = b_packed.shape[1]
+    assert b_packed.shape[0] * 32 == k, (b_packed.shape, k)
     rank = u.shape[0]
     assert u.shape == (rank, n) and v.shape == (rank, k), (u.shape, v.shape)
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
@@ -67,7 +69,7 @@ def binlr_matmul(x: Array, b_packed: Array, u: Array, v: Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((rank, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((rank, bk), lambda i, j, kk: (0, kk)),
         ],

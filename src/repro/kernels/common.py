@@ -16,31 +16,33 @@ Array = jax.Array
 
 
 def unpack_bits_tile(packed: Array, dtype) -> Array:
-    """(bn, bk/32) uint32 -> (bn, bk) ±1 in ``dtype``.
+    """(bk/32, bn) uint32 -> (bk, bn) ±1 in ``dtype`` (the Wᵀ tile).
 
-    Bit-test via precomputed per-lane masks (packed & (1<<j)) != 0 then a
-    single select — one AND + compare + select per element, no variable
-    shifts or integer arithmetic. ~2x faster than the shift/mul form in
-    interpret mode and the same VPU op class on TPU."""
-    bn, words = packed.shape
+    Word (g, o) holds the signs of columns g*32 .. g*32+31 of output row
+    o, so testing bit j of every word against a per-sublane mask gives a
+    (bk/32, 32, bn) block whose row-major merge is the tile in column
+    order: D_out stays on lanes, and the merge only folds a leading dim
+    into whole sublane tiles. One AND + compare + select per element;
+    the select runs in f32 and the cast to ``dtype`` comes last."""
+    words, bn = packed.shape
     masks = jnp.uint32(1) << jax.lax.broadcasted_iota(jnp.uint32,
-                                                      (1, 1, 32), 2)
-    pos = (packed[:, :, None] & masks) != 0
-    pm1 = jnp.where(pos, jnp.ones((), dtype), -jnp.ones((), dtype))
-    return pm1.reshape(bn, words * 32)
+                                                      (1, 32, 1), 1)
+    pm = jnp.where((packed[:, None, :] & masks) != 0, 1.0, -1.0)
+    return pm.reshape(words * 32, bn).astype(dtype)
 
 
 def accum_binlr_terms(acc, x, b, u_ref, v_ref, rank: int) -> None:
-    """acc += Σ_r ((x ⊙ v_r) @ Bᵀ) ⊙ u_r for one (bm, bk) x tile and an
-    already-expanded ±1 tile b (bn, bk); u_ref/v_ref hold (rank, bn) /
-    (rank, bk) blocks. The Python loop over ranks unrolls at trace
-    time; every term reuses the one expanded B tile, so extra ranks
-    cost MXU passes, not HBM bytes. u_r is constant along K, so folding
-    it into each step equals scaling once at the end."""
+    """acc += Σ_r ((x ⊙ v_r) @ b) ⊙ u_r for one (bm, bk) x tile and an
+    already-expanded ±1 tile b (bk, bn) — Wᵀ orientation, so each term
+    is a plain (bm, bk) @ (bk, bn) MXU pass; u_ref/v_ref hold
+    (rank, bn) / (rank, bk) blocks. The Python loop over ranks unrolls
+    at trace time; every term reuses the one expanded B tile, so extra
+    ranks cost MXU passes, not HBM bytes. u_r is constant along K, so
+    folding it into each step equals scaling once at the end."""
     for r in range(rank):
         xv = x * v_ref[r:r + 1, :]
         acc[...] += (jax.lax.dot_general(
-            xv, b.astype(xv.dtype), (((1,), (1,)), ((), ())),
+            xv, b.astype(xv.dtype), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
             * u_ref[r:r + 1, :].astype(jnp.float32))
 
@@ -64,13 +66,18 @@ def lowrank_epilogue(acc, acc_p, u_ref) -> Array:
 
 
 def expand_nm_tile(vals: Array, idx: Array, m: int, dtype) -> Array:
-    """(bn, g, n) values + (bn, g, n) int8 positions -> dense (bn, g*m).
+    """(n, bg, bn) values + (n, bg, bn) int8 positions -> the dense Wᵀ
+    tile (bg*m, bn).
 
-    Comparison one-hot expand: dense[o, g, p] = Σ_j vals[o,g,j]·[idx==p].
-    No scatter — pure VPU compares/multiplies, MXU-friendly output.
-    """
-    bn, g, n = vals.shape
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, m), 3)
-    hit = (idx[:, :, :, None].astype(jnp.int32) == pos)
-    dense = jnp.sum(jnp.where(hit, vals[:, :, :, None].astype(dtype), 0), axis=2)
-    return dense.reshape(bn, g * m)
+    Comparison one-hot expand: dense[g, p, o] = Σ_s vals[s,g,o]·[idx==p].
+    No scatter — pure VPU compares/selects on (bg, m, bn) blocks whose
+    row-major merge puts column g*m+p at row g*m+p; D_out stays on
+    lanes. Built in f32 and cast to ``dtype`` last."""
+    n, bg, bn = vals.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, m, 1), 1)
+    dense = None
+    for s in range(n):
+        hit = idx[s].astype(jnp.int32)[:, None, :] == pos
+        term = jnp.where(hit, vals[s].astype(jnp.float32)[:, None, :], 0.0)
+        dense = term if dense is None else dense + term
+    return dense.reshape(bg * m, bn).astype(dtype)

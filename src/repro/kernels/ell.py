@@ -198,7 +198,7 @@ def slab_ell_matmul(x: Array, vals: Array, idx: Array, b_packed: Array,
     n, k_max = vals.shape
     rank = u.shape[0]
     assert u.shape == (rank, n) and v.shape == (rank, k), (u.shape, v.shape)
-    assert b_packed.shape == (n, k // 32), (b_packed.shape, n, k)
+    assert b_packed.shape == (k // 32, n), (b_packed.shape, n, k)
     bm, bn = min(bm, m), min(bn, n)
     assert m % bm == 0 and n % bn == 0 and k % 32 == 0
 
@@ -213,7 +213,7 @@ def slab_ell_matmul(x: Array, vals: Array, idx: Array, b_packed: Array,
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, k_max), lambda i, j: (j, 0)),
             pl.BlockSpec((bn, k_max), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, k // 32), lambda i, j: (j, 0)),
+            pl.BlockSpec((k // 32, bn), lambda i, j: (0, j)),
             pl.BlockSpec((rank, bn), lambda i, j: (0, j)),
             pl.BlockSpec((rank, k), lambda i, j: (0, 0)),
         ],

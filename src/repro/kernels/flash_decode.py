@@ -1,31 +1,26 @@
 """Pallas TPU kernel: flash-decode — grouped-query single-token
-attention against a (possibly int8-quantized) KV cache.
+attention against a paged (possibly int8-quantized) KV cache.
 
-    out (B, KV, G, dh) = softmax(q · Kᵀ / √dh) · V     per (batch, kv head)
+    out (R, KV, G, dh) = softmax(q · Kᵀ / √dh) · V     per (row, kv head)
 
-Grid (B, KV, S/bs): each step streams one (bs, dh) K/V chunk HBM→VMEM,
-updates an online-softmax accumulator in VMEM scratch (running max m,
-normalizer l, weighted sum acc), and writes the normalized output on
-the last chunk. The (S,) score row is never materialized in HBM —
-exactly the flash-attention trick in its decode form, which is what the
-GSPMD path approximates with the "kv_seq over model" sharding.
+K/V live in a global block pool (n_blocks, KV, bs, dh) — head-major, so
+one (bs, dh) chunk of one head is a whole tile — and each request row
+owns a row of *logical→physical* block indices (`repro.serving.
+paged_cache`). Grid (R, KV, n_bt): step (r, h, j) streams pool block
+``block_tables[r, j]`` of head h HBM→VMEM through a scalar-prefetched
+BlockSpec index map, updates an online-softmax accumulator in VMEM
+scratch (running max m, normalizer l, weighted sum acc), and writes the
+normalized output on the last chunk. The (S,) score row is never
+materialized in HBM. Chunks past the row's valid length are skipped
+(`pl.when`), so decode work is proportional to each request's actual
+cache length, and a zero-length row returns exact zeros.
 
 int8 mode: K/V chunks arrive as int8 + per-(token, head) scales; the
-dequant multiply happens in VMEM on the chunk only (the HBM stream is
-the 1-byte payload — 2x less than bf16, the §Perf A2 term).
+scales fold into the scores (K) and the probabilities (V), so the HBM
+stream is the 1-byte payload and no dequantized chunk is built.
 
-Valid-length masking uses a scalar-prefetch length per batch row
-(cache slots beyond `length` are ignored).
-
-``flash_decode_paged`` is the block-table variant for the paged KV
-cache (`repro.serving.paged_cache`): K/V live in a global block pool
-(n_blocks, bs, KV, dh) and each request owns a row of *logical→physical*
-block indices. The same online-softmax kernel runs, but the K/V
-BlockSpec index maps read the physical block id from a scalar-prefetched
-block table — chunk ``ss`` of request ``bb`` streams pool block
-``block_tables[bb, ss]``. Chunks past the request's valid length are
-skipped (`pl.when`), so decode work is proportional to each request's
-actual cache length, not the table width.
+``flash_decode`` serves a contiguous (B, S, KV, dh) cache through the
+same kernel by viewing each row as S/bs consecutive pool blocks.
 """
 from __future__ import annotations
 
@@ -41,107 +36,12 @@ Array = jax.Array
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, bs: int, n_s: int, quant: bool):
-    b = pl.program_id(0)
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                 # (G, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)              # (bs, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)              # (bs, dh)
+def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                  bs: int, n_s: int, quant: bool):
     if quant:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-
-    scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    # mask positions beyond the valid cache length
-    pos = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < len_ref[b]
-    scores = jnp.where(valid, scores, NEG_INF)          # (G, bs)
-
-    m_prev = m_ref[...]                                 # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                         # (G, bs)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(s == n_s - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-
-
-def flash_decode(q: Array, k: Array, v: Array, lengths: Array,
-                 k_scale: Array | None = None,
-                 v_scale: Array | None = None,
-                 *, bs: int = 512, interpret: bool = False) -> Array:
-    """q (B, KV, G, dh) pre-scaled by 1/sqrt(dh); k/v (B, S, KV, dh)
-    [int8 when scales given, with k_scale/v_scale (B, S, KV)];
-    lengths (B,) int32. Returns (B, KV, G, dh)."""
-    b, kv, g, dh = q.shape
-    s = k.shape[1]
-    bs = min(bs, s)
-    if s % bs:
-        # pad the trailing chunk instead of asserting: padded slots sit
-        # at positions >= s >= lengths, so the existing valid-length
-        # mask already excludes them from the softmax
-        pad = (-s) % bs
-        padded = ((0, 0), (0, pad), (0, 0))
-        k = jnp.pad(k, padded + ((0, 0),))
-        v = jnp.pad(v, padded + ((0, 0),))
-        if k_scale is not None:
-            k_scale = jnp.pad(k_scale, padded)
-            v_scale = jnp.pad(v_scale, padded)
-        s += pad
-    quant = k_scale is not None
-    if not quant:       # dummy scale operands keep one kernel signature
-        k_scale = jnp.ones((b, s, kv), jnp.float32)
-        v_scale = jnp.ones((b, s, kv), jnp.float32)
-
-    grid = (b, kv, s // bs)
-    kernel = functools.partial(_kernel, bs=bs, n_s=grid[2], quant=quant)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bb, kk, ss, lens: (bb, kk, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda bb, kk, ss, lens: (bb, ss, kk, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda bb, kk, ss, lens: (bb, ss, kk, 0)),
-            pl.BlockSpec((1, bs, 1), lambda bb, kk, ss, lens: (bb, ss, kk)),
-            pl.BlockSpec((1, bs, 1), lambda bb, kk, ss, lens: (bb, ss, kk)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bb, kk, ss, lens: (bb, kk, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, dh), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
-        interpret=interpret,
-    )(lengths, q, k, v, k_scale, v_scale)
-
-
-# ------------------------------------------------------------------
-# Paged (block-table) variant
-# ------------------------------------------------------------------
-
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_ref, l_ref, acc_ref,
-                  *, bs: int, n_s: int, quant: bool):
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     s = pl.program_id(2)
 
@@ -151,32 +51,26 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # chunks wholly past this request's cache length carry no valid
-    # tokens — skip the dot-products (work ∝ actual length, not table
-    # width; a zero-length request touches no chunk at all)
     @pl.when(s * bs < len_ref[b])
     def _accumulate():
         q = q_ref[0, 0].astype(jnp.float32)             # (G, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)          # (bs, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)          # (bs, dh)
-        if quant:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-
+        k = k_ref[0, 0].astype(jnp.float32)             # (bs, dh)
+        v = v_ref[0, 0].astype(jnp.float32)             # (bs, dh)
         scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
+        if quant:
+            scores = scores * ks_ref[0, 0]              # (1, bs) scales
         pos = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        valid = pos < len_ref[b]
-        scores = jnp.where(valid, scores, NEG_INF)      # (G, bs)
+        scores = jnp.where(pos < len_ref[b], scores, NEG_INF)  # (G, bs)
 
         m_prev = m_ref[...]                             # (G, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)                     # (G, bs)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pv = p * vs_ref[0, 0] if quant else p
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            pv, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(s == n_s - 1)
@@ -193,49 +87,76 @@ def flash_decode_paged(q: Array, k_pool: Array, v_pool: Array,
     """Flash-decode against a paged KV cache.
 
     q (R, KV, G, dh) pre-scaled by 1/sqrt(dh); k_pool/v_pool
-    (n_blocks, bs, KV, dh) [int8 when scales given, with k_scale/v_scale
-    (n_blocks, bs, KV)]; block_tables (R, n_bt) int32 physical block ids
+    (n_blocks, KV, bs, dh) [int8 when scales given, with k_scale/v_scale
+    (n_blocks, KV, bs)]; block_tables (R, n_bt) int32 physical block ids
     per logical chunk (entries past a request's length may hold
     anything in range — they are never read); lengths (R,) int32 valid
     tokens per request. Returns (R, KV, G, dh); zero-length rows
     return zeros."""
     r, kv, g, dh = q.shape
-    n_blocks, bs = k_pool.shape[0], k_pool.shape[1]
+    n_blocks, _, bs, _ = k_pool.shape
     n_bt = block_tables.shape[1]
     quant = k_scale is not None
-    if not quant:
-        k_scale = jnp.ones((n_blocks, bs, kv), jnp.float32)
-        v_scale = jnp.ones((n_blocks, bs, kv), jnp.float32)
 
-    grid = (r, kv, n_bt)
-    kernel = functools.partial(_paged_kernel, bs=bs, n_s=n_bt, quant=quant)
+    def chunk(bb, kk, ss, bt, lens):     # chunk ss of row bb, head kk
+        return (bt[bb, ss], kk, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, g, dh),
+                     lambda bb, kk, ss, bt, lens: (bb, kk, 0, 0)),
+        pl.BlockSpec((1, 1, bs, dh), chunk),
+        pl.BlockSpec((1, 1, bs, dh), chunk),
+    ]
+    operands = [q, k_pool, v_pool]
+    if quant:
+        # (1, bs) scale rows: the token axis rides the lanes
+        in_specs += [pl.BlockSpec((1, 1, 1, bs), chunk)] * 2
+        operands += [k_scale.reshape(n_blocks, kv, 1, bs),
+                     v_scale.reshape(n_blocks, kv, 1, bs)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,        # block_tables, lengths
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh),
-                         lambda bb, kk, ss, bt, lens: (bb, kk, 0, 0)),
-            # chunk ss of request bb streams physical pool block
-            # bt[bb, ss] — the paged indirection lives entirely in the
-            # scalar-prefetched index map
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bb, kk, ss, bt, lens: (bt[bb, ss], 0, kk, 0)),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bb, kk, ss, bt, lens: (bt[bb, ss], 0, kk, 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda bb, kk, ss, bt, lens: (bt[bb, ss], 0, kk)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda bb, kk, ss, bt, lens: (bt[bb, ss], 0, kk)),
-        ],
+        grid=(r, kv, n_bt),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, dh),
                                lambda bb, kk, ss, bt, lens: (bb, kk, 0, 0)),
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, dh), jnp.float32)],
     )
+    kernel = functools.partial(_paged_kernel, bs=bs, n_s=n_bt, quant=quant)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, kv, g, dh), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, q, k_pool, v_pool, k_scale, v_scale)
+    )(block_tables, lengths, *operands)
+
+
+def flash_decode(q: Array, k: Array, v: Array, lengths: Array,
+                 k_scale: Array | None = None,
+                 v_scale: Array | None = None,
+                 *, bs: int = 512, interpret: bool = False) -> Array:
+    """q (B, KV, G, dh) pre-scaled by 1/sqrt(dh); k/v (B, S, KV, dh)
+    [int8 when scales given, with k_scale/v_scale (B, S, KV)];
+    lengths (B,) int32. Returns (B, KV, G, dh).
+
+    The contiguous cache is re-laid out as a pool of bs-token blocks
+    (row b owns blocks b*S/bs .. (b+1)*S/bs - 1) and served by the paged
+    kernel; a trailing partial chunk is zero-padded (padded slots sit at
+    positions >= S >= lengths, so the length mask excludes them)."""
+    b, s, kv, dh = k.shape
+    bs = min(bs, s)
+    pad = (-s) % bs
+    n_c = (s + pad) // bs
+
+    def to_pool(t):
+        t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        t = t.reshape((b, n_c, bs) + t.shape[2:])
+        t = jnp.moveaxis(t, 3, 2)                     # head ahead of bs
+        return t.reshape((b * n_c,) + t.shape[2:])
+
+    tables = jnp.arange(b * n_c, dtype=jnp.int32).reshape(b, n_c)
+    scales = ((to_pool(k_scale), to_pool(v_scale))
+              if k_scale is not None else (None, None))
+    return flash_decode_paged(q, to_pool(k), to_pool(v), tables, lengths,
+                              *scales, interpret=interpret)

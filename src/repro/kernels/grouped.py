@@ -129,12 +129,12 @@ def slab_ell_matmul_g(x: Array, vals: Array, idx: Array, b_packed: Array,
                       u: Array, v: Array,
                       *, bm: int = 128, bn: int = 256,
                       jc=None, interpret: bool = False) -> Array:
-    """Full SLaB with ELL sparse part, per expert. b_packed (E, N, K/32)."""
+    """Full SLaB with ELL sparse part, per expert. b_packed (E, K/32, N)."""
     e, m, k = x.shape
     _, n, k_max = vals.shape
     rank = u.shape[1]
     assert u.shape == (e, rank, n) and v.shape == (e, rank, k)
-    assert b_packed.shape == (e, n, k // 32), (b_packed.shape, e, n, k)
+    assert b_packed.shape == (e, k // 32, n), (b_packed.shape, e, n, k)
     bm, bn = min(bm, m), min(bn, n)
     assert m % bm == 0 and n % bn == 0 and k % 32 == 0
     kernel = functools.partial(_kernel_slab_ell_g,
@@ -146,7 +146,7 @@ def slab_ell_matmul_g(x: Array, vals: Array, idx: Array, b_packed: Array,
             _espec((bm, k), lambda i, j: (i, 0)),
             _espec((bn, k_max), lambda i, j: (j, 0)),
             _espec((bn, k_max), lambda i, j: (j, 0)),
-            _espec((bn, k // 32), lambda i, j: (j, 0)),
+            _espec((k // 32, bn), lambda i, j: (0, j)),
             _espec((rank, bn), lambda i, j: (0, j)),
             _espec((rank, k), lambda i, j: (0, 0)),
         ],
@@ -171,9 +171,7 @@ def _kernel_nm_g(x_ref, val_ref, idx_ref, o_ref, acc,
 
     x = x_ref[0]
     w = expand_nm_tile(val_ref[0], idx_ref[0], m_pat, x.dtype)
-    acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -183,9 +181,9 @@ def _kernel_nm_g(x_ref, val_ref, idx_ref, o_ref, acc,
 def nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
                 *, bm: int = 256, bn: int = 256, bk: int = 512,
                 interpret: bool = False) -> Array:
-    """x (E, M, K); vals/idx (E, N, K/m, n) -> (E, M, N)."""
+    """x (E, M, K); vals/idx (E, n, K/m, N) -> (E, M, N)."""
     e, m, k = x.shape
-    _, n, n_grp, n_keep = vals.shape
+    _, n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0 and bk % m_pat == 0
@@ -197,8 +195,8 @@ def nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             _espec((bm, bk), lambda i, j, kk: (i, kk)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
         ],
         out_specs=_espec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), x.dtype),
@@ -230,7 +228,7 @@ def _kernel_dense_g(x_ref, ws_ref, bp_ref, u_ref, v_ref, o_ref, acc,
 def slab_matmul_g(x: Array, w_s: Array, b_packed: Array, u: Array, v: Array,
                   *, bm: int = 256, bn: int = 256, bk: int = 512,
                   interpret: bool = False) -> Array:
-    """Dense-masked SLaB per expert. w_s (E,N,K); b_packed (E,N,K/32)."""
+    """Dense-masked SLaB per expert. w_s (E,N,K); b_packed (E,K/32,N)."""
     e, m, k = x.shape
     n = w_s.shape[1]
     rank = u.shape[1]
@@ -245,7 +243,7 @@ def slab_matmul_g(x: Array, w_s: Array, b_packed: Array, u: Array, v: Array,
         in_specs=[
             _espec((bm, bk), lambda i, j, kk: (i, kk)),
             _espec((bn, bk), lambda i, j, kk: (j, kk)),
-            _espec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            _espec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             _espec((rank, bn), lambda i, j, kk: (0, j)),
             _espec((rank, bk), lambda i, j, kk: (0, kk)),
         ],
@@ -266,9 +264,7 @@ def _kernel_nm_full_g(x_ref, val_ref, idx_ref, bp_ref, u_ref, v_ref,
 
     x = x_ref[0]
     w = expand_nm_tile(val_ref[0], idx_ref[0], m_pat, x.dtype)
-    acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
     b = unpack_bits_tile(bp_ref[0], x.dtype)
     accum_binlr_terms(acc, x, b, u_ref[0], v_ref[0], rank)
 
@@ -281,9 +277,9 @@ def slab_nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
                      b_packed: Array, u: Array, v: Array,
                      *, bm: int = 256, bn: int = 256, bk: int = 512,
                      interpret: bool = False) -> Array:
-    """N:M SLaB per expert. vals/idx (E, N, K/m, n)."""
+    """N:M SLaB per expert. vals/idx (E, n, K/m, N)."""
     e, m, k = x.shape
-    _, n, n_grp, n_keep = vals.shape
+    _, n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k
     rank = u.shape[1]
     assert u.shape == (e, rank, n) and v.shape == (e, rank, k)
@@ -299,9 +295,9 @@ def slab_nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             _espec((bm, bk), lambda i, j, kk: (i, kk)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            _espec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            _espec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             _espec((rank, bn), lambda i, j, kk: (0, j)),
             _espec((rank, bk), lambda i, j, kk: (0, kk)),
         ],
@@ -373,9 +369,7 @@ def _kernel_nm_lr_g(x_ref, val_ref, idx_ref, u_ref, v_ref, o_ref,
 
     x = x_ref[0]
     w = expand_nm_tile(val_ref[0], idx_ref[0], m_pat, x.dtype)
-    acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
     accum_lowrank_proj(acc_p, x, v_ref[0])
 
     @pl.when(k == n_k - 1)
@@ -390,7 +384,7 @@ def slab_nm_lr_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
                         interpret: bool = False) -> Array:
     """N:M sparse + rank-r low-rank, no binary, per expert."""
     e, m, k = x.shape
-    _, n, n_grp, n_keep = vals.shape
+    _, n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k
     rank = u.shape[1]
     assert u.shape == (e, rank, n) and v.shape == (e, rank, k)
@@ -404,8 +398,8 @@ def slab_nm_lr_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             _espec((bm, bk), lambda i, j, kk: (i, kk)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            _espec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            _espec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
             _espec((rank, bn), lambda i, j, kk: (0, j)),
             _espec((rank, bk), lambda i, j, kk: (0, kk)),
         ],
@@ -437,10 +431,10 @@ def _kernel_binlr_g(x_ref, bp_ref, u_ref, v_ref, o_ref, acc,
 def binlr_matmul_g(x: Array, b_packed: Array, u: Array, v: Array,
                    *, bm: int = 256, bn: int = 256, bk: int = 512,
                    interpret: bool = False) -> Array:
-    """Binary ⊙ rank-r per expert. b_packed (E, N, K/32) uint32."""
+    """Binary ⊙ rank-r per expert. b_packed (E, K/32, N) uint32."""
     e, m, k = x.shape
-    n = b_packed.shape[1]
-    assert b_packed.shape[2] * 32 == k
+    n = b_packed.shape[2]
+    assert b_packed.shape[1] * 32 == k
     rank = u.shape[1]
     assert u.shape == (e, rank, n) and v.shape == (e, rank, k)
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
@@ -452,7 +446,7 @@ def binlr_matmul_g(x: Array, b_packed: Array, u: Array, v: Array,
         grid=grid,
         in_specs=[
             _espec((bm, bk), lambda i, j, kk: (i, kk)),
-            _espec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            _espec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             _espec((rank, bn), lambda i, j, kk: (0, j)),
             _espec((rank, bk), lambda i, j, kk: (0, kk)),
         ],

@@ -1,6 +1,6 @@
 """Pallas TPU kernel: N:M semi-structured sparse matmul.
 
-    y (M, N) = x @ W_Sᵀ,  W_S streamed as (values (N, K/m, n), idx int8)
+    y (M, N) = x @ W_Sᵀ,  W_S streamed as (values (n, K/m, N), idx int8)
 
 2:4 at b=16 streams 9/16ths of the dense bytes (values + 2-bit indices,
 int8-stored); the dense tile is rebuilt in VMEM by comparison-one-hot
@@ -28,10 +28,8 @@ def _kernel(x_ref, val_ref, idx_ref, o_ref, acc_ref, *, n_k: int, m_pat: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]                                        # (bm, bk)
-    w = expand_nm_tile(val_ref[...], idx_ref[...], m_pat, x.dtype)  # (bn, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    w = expand_nm_tile(val_ref[...], idx_ref[...], m_pat, x.dtype)  # (bk, bn)
+    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -41,9 +39,9 @@ def _kernel(x_ref, val_ref, idx_ref, o_ref, acc_ref, *, n_k: int, m_pat: int):
 def nm_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
               *, bm: int = 256, bn: int = 256, bk: int = 512,
               interpret: bool = False) -> Array:
-    """x (M, K); vals/idx (N, K/m, n) -> (M, N)."""
+    """x (M, K); vals/idx (n, K/m, N) -> (M, N)."""
     m, k = x.shape
-    n, n_grp, n_keep = vals.shape
+    n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k, (vals.shape, m_pat, k)
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0 and bk % m_pat == 0
@@ -56,8 +54,8 @@ def nm_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),

@@ -340,31 +340,36 @@ def binlr_g(x: Array, b_packed: Array, u: Array, v: Array,
     return y[:, :m]
 
 
-def flash_decode_attention(q: Array, k: Array, v: Array, lengths: Array,
-                           k_scale: Optional[Array] = None,
-                           v_scale: Optional[Array] = None,
-                           bs: int = 512,
-                           interpret: Optional[bool] = None) -> Array:
-    """Grouped-query decode attention (optionally int8 KV) via the
-    flash-decode kernel. q (B, KV, G, dh) pre-scaled by 1/sqrt(dh)."""
-    from repro.kernels.flash_decode import flash_decode
-    interpret = _on_cpu() if interpret is None else interpret
-    return flash_decode(q, k, v, lengths, k_scale, v_scale, bs=bs,
-                        interpret=interpret)
-
-
 def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
                                  block_tables: Array, lengths: Array,
                                  k_scale: Optional[Array] = None,
                                  v_scale: Optional[Array] = None,
                                  interpret: Optional[bool] = None) -> Array:
     """Paged (block-table) grouped-query decode attention. q (R, KV, G,
-    dh) pre-scaled; k_pool/v_pool (n_blocks, bs, KV, dh);
-    block_tables (R, n_bt); lengths (R,) — zero-length rows return 0."""
+    dh) pre-scaled; k_pool/v_pool (n_blocks, KV, bs, dh);
+    block_tables (R, n_bt); lengths (R,) — zero-length rows return 0.
+
+    Under a multi-device mesh the kernel runs inside ``shard_map`` (the
+    chip's compiler cannot partition a Pallas kernel): each device
+    attends with its KV heads when they divide the "model" axis, and
+    with all of them otherwise."""
     from repro.kernels.flash_decode import flash_decode_paged
+    from repro.runtime.meshctx import current_mesh
     interpret = _on_cpu() if interpret is None else interpret
-    return flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
-                              k_scale, v_scale, interpret=interpret)
+    fn = functools.partial(flash_decode_paged, interpret=interpret)
+    args = (q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale)
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+    n_model = dict(mesh.shape).get("model", 1)
+    heads = P(None, "model" if n_model > 1 and q.shape[1] % n_model == 0
+              else None)
+    scale = heads if k_scale is not None else None
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(heads, heads, heads, P(), P(), scale, scale),
+        out_specs=heads, check_vma=False)(*args)
 
 
 def slab_linear_kernel(x: Array, packed: SLaBPacked, **kw) -> Array:

@@ -38,8 +38,8 @@ def lowrank_ref(x: Array, u: Array, v: Array) -> Array:
 
 
 def nm_matmul_ref(x: Array, vals: Array, idx: Array, m: int) -> Array:
-    """y = x @ W_Sᵀ with W_S in N:M packed form."""
-    n = vals.shape[-1]
+    """y = x @ W_Sᵀ with W_S in N:M packed form (vals/idx (n, K/m, N))."""
+    n = vals.shape[0]
     d_in = vals.shape[1] * m
     w = unpack_nm(NMPacked(vals, idx, n, m, d_in))
     return x.astype(jnp.float32) @ w.astype(jnp.float32).T

@@ -64,7 +64,7 @@ def _kernel_dense(x_ref, ws_ref, bp_ref, u_ref, v_ref, o_ref,
 def slab_matmul(x: Array, w_s: Array, b_packed: Array, u: Array, v: Array,
                 *, bm: int = 256, bn: int = 256, bk: int = 512,
                 interpret: bool = False) -> Array:
-    """x (M,K); w_s (N,K); b_packed (N,K/32); u (R,N); v (R,K) -> (M,N)."""
+    """x (M,K); w_s (N,K); b_packed (K/32,N); u (R,N); v (R,K) -> (M,N)."""
     m, k = x.shape
     n = w_s.shape[0]
     rank = u.shape[0]
@@ -80,7 +80,7 @@ def slab_matmul(x: Array, w_s: Array, b_packed: Array, u: Array, v: Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bk), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((rank, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((rank, bk), lambda i, j, kk: (0, kk)),
         ],
@@ -103,9 +103,7 @@ def _kernel_nm(x_ref, val_ref, idx_ref, bp_ref, u_ref, v_ref, o_ref,
 
     x = x_ref[...]
     w = expand_nm_tile(val_ref[...], idx_ref[...], m_pat, x.dtype)
-    acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
     b = unpack_bits_tile(bp_ref[...], x.dtype)
     accum_binlr_terms(acc, x, b, u_ref, v_ref, rank)
 
@@ -118,9 +116,10 @@ def slab_nm_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
                    b_packed: Array, u: Array, v: Array,
                    *, bm: int = 256, bn: int = 256, bk: int = 512,
                    interpret: bool = False) -> Array:
-    """N:M variant. vals/idx (N, K/m, n); u (R, N); v (R, K)."""
+    """N:M variant. vals/idx (n, K/m, N); b_packed (K/32, N); u (R, N);
+    v (R, K)."""
     m, k = x.shape
-    n, n_grp, n_keep = vals.shape
+    n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k
     rank = u.shape[0]
     assert u.shape == (rank, n) and v.shape == (rank, k), (u.shape, v.shape)
@@ -137,9 +136,9 @@ def slab_nm_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bk // 32), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((bk // 32, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((rank, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((rank, bk), lambda i, j, kk: (0, kk)),
         ],
@@ -218,9 +217,7 @@ def _kernel_nm_lr(x_ref, val_ref, idx_ref, u_ref, v_ref, o_ref,
 
     x = x_ref[...]
     w = expand_nm_tile(val_ref[...], idx_ref[...], m_pat, x.dtype)
-    acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
     accum_lowrank_proj(acc_p, x, v_ref)
 
     @pl.when(k == n_k - 1)
@@ -232,9 +229,9 @@ def slab_nm_lr_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
                       u: Array, v: Array,
                       *, bm: int = 256, bn: int = 256, bk: int = 512,
                       interpret: bool = False) -> Array:
-    """N:M sparse + rank-r low-rank, no binary. vals/idx (N, K/m, n)."""
+    """N:M sparse + rank-r low-rank, no binary. vals/idx (n, K/m, N)."""
     m, k = x.shape
-    n, n_grp, n_keep = vals.shape
+    n_keep, n_grp, n = vals.shape
     assert n_grp * m_pat == k
     rank = u.shape[0]
     assert u.shape == (rank, n) and v.shape == (rank, k), (u.shape, v.shape)
@@ -249,8 +246,8 @@ def slab_nm_lr_matmul(x: Array, vals: Array, idx: Array, m_pat: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
             pl.BlockSpec((rank, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((rank, bk), lambda i, j, kk: (0, kk)),
         ],

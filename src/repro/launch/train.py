@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 from repro.data import SyntheticCorpus
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -37,7 +38,7 @@ from repro.runtime.step import make_train_fn
 
 
 def build_mesh(data: int, model: int) -> Mesh:
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def train(arch: str, smoke: bool, steps: int, batch: int, seq: int,

@@ -227,7 +227,7 @@ def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
     """One-token decode against a paged KV cache (one layer's pool).
 
     x (R, 1, D); pool a single-layer ``serving.paged_cache.PagedKVCache``
-    slice (k/v (n_blocks, bs, KV, dh)); block_tables (R, n_bt) int32;
+    slice (k/v (n_blocks, KV, bs, dh)); block_tables (R, n_bt) int32;
     lengths (R,) tokens already cached per row (also the write
     position); active (R,) bool — inactive rows write nothing and
     return zeros. Returns (out (R, 1, D), updated pool).

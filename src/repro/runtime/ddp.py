@@ -27,7 +27,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import lm
 from repro.models.common import ArchConfig
@@ -102,11 +101,11 @@ def build_compressed_ddp_step(cfg: ArchConfig, acfg: AdamWConfig,
     rep = P()
     shd = P(AXIS)
     batch_spec = {"inputs": shd, "labels": shd}
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     ))
 
 

@@ -102,13 +102,13 @@ class Engine:
                                shed=ecfg.shed,
                                max_evictions=ecfg.max_evictions)
         self.paged = init_paged_cache(cfg, ecfg.n_blocks, ecfg.block_size)
+        self._pool_sharding = None
         if planner is not None:
             from repro.models.common import is_axes_leaf
-            self.paged = jax.device_put(
-                self.paged, jax.tree.map(
-                    lambda ax, leaf: planner.sharding(ax, leaf.shape),
-                    paged_cache_axes(cfg), self.paged,
-                    is_leaf=is_axes_leaf))
+            self._pool_sharding = jax.tree.map(
+                lambda ax, leaf: planner.sharding(ax, leaf.shape),
+                paged_cache_axes(cfg), self.paged, is_leaf=is_axes_leaf)
+            self.paged = jax.device_put(self.paged, self._pool_sharding)
         self._steps: Dict[int, object] = {}     # chunk C -> jitted step
         self.n_steps = 0
 
@@ -121,11 +121,14 @@ class Engine:
         valid position (prefill completion / decode output), the
         updated pool, and a per-row ALL-positions-finite flag (the
         numerical guard; ``force_nan`` poisons chosen rows — the
-        fault-injection hook, all zeros in normal serving)."""
-        cfg, params = self.cfg, self.params
+        fault-injection hook, all zeros in normal serving). The weights
+        are an argument, never a closure: closed-over arrays would be
+        compiled into the program as constants."""
+        cfg, pool_sharding = self.cfg, self._pool_sharding
 
-        def step(paged: PagedKVCache, tables: Array, lengths: Array,
-                 tokens: Array, n_valid: Array, force_nan: Array):
+        def step(params, paged: PagedKVCache, tables: Array,
+                 lengths: Array, tokens: Array, n_valid: Array,
+                 force_nan: Array):
             last0 = jnp.zeros((tokens.shape[0],), jnp.int32)
             ok0 = jnp.ones((tokens.shape[0],), bool)
 
@@ -146,26 +149,48 @@ class Engine:
             xs = (jnp.moveaxis(tokens, 1, 0), jnp.arange(c))
             (paged, _, last, ok), _ = jax.lax.scan(
                 body, (paged, lengths, last0, ok0), xs)
+            if pool_sharding is not None:
+                # the pool leaves each step in the layout it came in
+                paged = jax.lax.with_sharding_constraint(paged,
+                                                         pool_sharding)
             return paged, last, ok
 
         return jax.jit(step)
 
-    def _run_step(self, tokens: np.ndarray, n_valid: np.ndarray,
-                  force_nan: np.ndarray):
-        c = tokens.shape[1]
-        if c not in self._steps:
-            self._steps[c] = self._step_fn(c)
-        args = (self.paged,
+    def _step_args(self, tokens: np.ndarray, n_valid: np.ndarray,
+                   force_nan: np.ndarray):
+        return (self.params, self.paged,
                 jnp.asarray(self.sched.block_table),
                 jnp.asarray(self.sched.lengths),
                 jnp.asarray(tokens), jnp.asarray(n_valid),
                 jnp.asarray(force_nan))
-        if self.mesh is not None:
-            from repro.runtime.meshctx import use_mesh
+
+    def compile(self) -> float:
+        """Compile both step programs (chunk C and the C=1 decode step)
+        ahead of serving, so that no request waits on the compiler.
+        Returns the seconds spent; ``run`` compiles lazily otherwise."""
+        from repro.runtime.meshctx import use_mesh
+        t0 = time.monotonic()
+        r = self.sched.n_slots
+        for c in sorted({self.ecfg.prefill_chunk, 1}):
+            if c in self._steps:
+                continue
+            args = self._step_args(np.zeros((r, c), np.int32),
+                                   np.zeros((r,), np.int32),
+                                   np.zeros((r,), bool))
             with use_mesh(self.mesh):
-                self.paged, last, ok = self._steps[c](*args)
-        else:
-            self.paged, last, ok = self._steps[c](*args)
+                self._steps[c] = self._step_fn(c).lower(*args).compile()
+        return time.monotonic() - t0
+
+    def _run_step(self, tokens: np.ndarray, n_valid: np.ndarray,
+                  force_nan: np.ndarray):
+        from repro.runtime.meshctx import use_mesh
+        c = tokens.shape[1]
+        if c not in self._steps:
+            self._steps[c] = self._step_fn(c)
+        with use_mesh(self.mesh):
+            self.paged, last, ok = self._steps[c](
+                *self._step_args(tokens, n_valid, force_nan))
         return np.asarray(last), np.asarray(ok)
 
     # -- fault plumbing ----------------------------------------------------

@@ -4,8 +4,11 @@ host-side free-list allocator.
 Layout. One global pool per layer holds every request's K/V in
 fixed-size blocks:
 
-    k, v     (L, n_blocks, block_size, KV, dh)      cfg.dtype | int8
-    k_scale  (L, n_blocks, block_size, KV) f32      int8 mode only
+    k, v     (L, n_blocks, KV, block_size, dh)      cfg.dtype | int8
+    k_scale  (L, n_blocks, KV, block_size) f32      int8 mode only
+
+Head-major blocks: one head's (block_size, dh) chunk is a whole tile of
+the ``flash_decode_paged`` kernel's BlockSpec.
 
 A request's cache is the *logical* concatenation of the blocks its
 block-table row names: ``block_tables[r, j]`` is the physical block
@@ -19,9 +22,9 @@ host-side numpy arrays owned by the scheduler and shipped as ordinary
 jit arguments each step, so allocation/eviction never touches device
 state and the step functions stay pure.
 
-Writes go through ``paged_write``: a flat scatter at
-``block_id * block_size + offset`` with ``mode="drop"`` so inactive
-rows (idle slots, exhausted prefill rows) write nowhere. Reads go
+Writes go through ``paged_write``: a scatter at ``(block_id, :,
+offset)`` with ``mode="drop"`` so inactive rows (idle slots, exhausted
+prefill rows) write nowhere. Reads go
 through the ``flash_decode_paged`` kernel, whose BlockSpec index maps
 consume the block table as a scalar-prefetch operand.
 """
@@ -40,21 +43,21 @@ Array = jax.Array
 class PagedKVCache(NamedTuple):
     """Stacked per-layer block pools (exactly one pool per attention
     layer; families without KV attention don't page)."""
-    k: Array                        # (L, n_blocks, bs, KV, dh)
-    v: Array                        # (L, n_blocks, bs, KV, dh)
-    k_scale: Optional[Array] = None   # (L, n_blocks, bs, KV) f32, int8 only
+    k: Array                        # (L, n_blocks, KV, bs, dh)
+    v: Array                        # (L, n_blocks, KV, bs, dh)
+    k_scale: Optional[Array] = None   # (L, n_blocks, KV, bs) f32, int8 only
     v_scale: Optional[Array] = None
 
     # indexed from the END so the properties are correct both for the
-    # stacked (L, n_blocks, bs, KV, dh) layout and for a single-layer
-    # (n_blocks, bs, KV, dh) slice riding a layer scan
+    # stacked (L, n_blocks, KV, bs, dh) layout and for a single-layer
+    # (n_blocks, KV, bs, dh) slice riding a layer scan
     @property
     def n_blocks(self) -> int:
         return self.k.shape[-4]
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
 
 def init_paged_cache(cfg: ArchConfig, n_blocks: int,
@@ -63,7 +66,7 @@ def init_paged_cache(cfg: ArchConfig, n_blocks: int,
         raise ValueError(
             f"paged KV serving needs a KV-attention family, not "
             f"{cfg.family!r} (SSM state is O(1) — it doesn't page)")
-    shp = (cfg.n_layers, n_blocks, block_size, cfg.n_kv, cfg.d_head)
+    shp = (cfg.n_layers, n_blocks, cfg.n_kv, block_size, cfg.d_head)
     if cfg.kv_quant:
         sshp = shp[:-1]
         return PagedKVCache(jnp.zeros(shp, jnp.int8),
@@ -79,9 +82,9 @@ def paged_cache_axes(cfg: ArchConfig) -> PagedKVCache:
     block dim split would scatter one stream across shards — while the
     KV-head dim TP-shards over "model" when it divides (each shard
     serves its heads' pool; the flash-decode grid is per-kv-head)."""
-    scale_ax = (("layers", "kv_blocks", None, "kv_heads")
+    scale_ax = (("layers", "kv_blocks", "kv_heads", None)
                 if cfg.kv_quant else None)
-    ax = ("layers", "kv_blocks", None, "kv_heads", None)
+    ax = ("layers", "kv_blocks", "kv_heads", None, None)
     return PagedKVCache(ax, ax, scale_ax, scale_ax)
 
 
@@ -89,14 +92,12 @@ def paged_write(pool: Array, new: Array, block_ids: Array, offsets: Array,
                 active: Array) -> Array:
     """Scatter one token per request row into a (single-layer) pool.
 
-    pool (n_blocks, bs, KV, dh) | (n_blocks, bs, KV); new (R, KV, dh) |
+    pool (n_blocks, KV, bs, dh) | (n_blocks, KV, bs); new (R, KV, dh) |
     (R, KV); block_ids/offsets (R,) int32; active (R,) bool. Inactive
     rows are routed out of bounds and dropped by the scatter."""
-    n_blocks, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape((n_blocks * bs,) + pool.shape[2:])
-    idx = jnp.where(active, block_ids * bs + offsets, n_blocks * bs)
-    flat = flat.at[idx].set(new.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+    blk = jnp.where(active, block_ids, pool.shape[0])
+    return pool.at[blk, :, offsets].set(new.astype(pool.dtype),
+                                        mode="drop")
 
 
 class BlockAllocator:

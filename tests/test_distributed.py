@@ -21,6 +21,7 @@ def run_py(body: str, timeout=560) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ,
                PYTHONPATH=os.path.join(REPO, "src"),
@@ -36,7 +37,7 @@ def test_planner_rules():
         from repro import configs
         from repro.runtime.sharding import Planner
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
 
         cfg = configs.get("stablelm_12b")          # 32H x 160dh
         pl = Planner(mesh, cfg)
@@ -48,7 +49,7 @@ def test_planner_rules():
         pl2 = Planner(mesh, cfg2)
         assert pl2.spec(("embed", "heads"), (3072, 3072)) == P("data", "model")
         # but a 16-way model axis cannot shard 24 heads:
-        mesh16 = jax.make_mesh((1, 8), ("data", "model"))
+        mesh16 = make_mesh((1, 8), ("data", "model"))
         pl16 = Planner(mesh16, cfg2)
         # 24*128/8 = 384 = 3 heads -> fine on 8; simulate 16 via unit check
         from repro.runtime.sharding import axis_constraints
@@ -85,7 +86,7 @@ def test_train_step_parallel_matches_single_device():
 
         results = {}
         for name, shape in [("single", (1, 1)), ("mesh", (2, 4))]:
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             pl = Planner(mesh, cfg)
             p_sh = pl.tree_shardings(axes, params)
             p = jax.device_put(params, p_sh)
@@ -117,7 +118,7 @@ def test_compressed_ddp_step_runs_and_learns():
 
         cfg = configs.get("llama2_7b", smoke=True).with_(dtype=jnp.float32)
         acfg = AdamWConfig(lr=3e-3, warmup_steps=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         params, _ = lm.init(cfg, jax.random.PRNGKey(0))
         opt = adamw_init(params, acfg)
         err = ddp.init_error_buffers(params)
@@ -148,7 +149,7 @@ def test_compressed_vs_uncompressed_ddp_close():
 
         cfg = configs.get("llama2_7b", smoke=True).with_(dtype=jnp.float32)
         acfg = AdamWConfig(lr=1e-3, warmup_steps=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         corpus = SyntheticCorpus(cfg.vocab, seed=0)
 
         outs = {}
@@ -182,8 +183,8 @@ def test_elastic_restore_across_meshes():
 
         cfg = configs.get("llama2_7b", smoke=True).with_(dtype=jnp.float32)
         acfg = AdamWConfig()
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))  # "shrunk" job
+        mesh_a = make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_mesh((2, 2), ("data", "model"))  # "shrunk" job
 
         params, axes = lm.init(cfg, jax.random.PRNGKey(0))
         pl_a = Planner(mesh_a, cfg)
@@ -221,7 +222,7 @@ def test_packed_planner_specs_on_mesh():
         from jax.sharding import PartitionSpec as P
         from benchmarks.common import synthetic_pruned_packed
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = configs.get("stablelm_12b", smoke=True).with_(
             dtype=jnp.float32, n_layers=4)
         _, packed, rep = synthetic_pruned_packed(
@@ -273,7 +274,7 @@ def test_packed_vs_dense_decode_parity_on_mesh():
         dense_c, stats, decs = compress_model(cfg, params, cal, plan=plan,
                                               keep_decompositions=True)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         pl = Planner(mesh, cfg)
         dense_sh = jax.device_put(dense_c, pl.tree_shardings(axes, dense_c))
         packed, rep = pack_plan_decs(
@@ -328,7 +329,7 @@ def test_packed_degraded_replication():
         cfg = configs.get("stablelm_12b", smoke=True).with_(
             dtype=jnp.float32, d_ff=250)
         _, packed, _ = synthetic_pruned_packed(cfg, lambda l: 0.5)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         pl = Planner(mesh, cfg)
         axes = merge_packed_axes(lm.param_axes(cfg), packed)
         specs = pl.tree_specs(axes, packed)
@@ -367,7 +368,7 @@ def test_dryrun_cell_subprocess_smoke():
     out = run_py("""
         from repro import configs
         from repro.launch import cell as cell_lib
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = configs.get("llama2_7b", smoke=True)
         shape = configs.ShapeSpec("train_4k", "train", 128, 8)
         res = cell_lib.run_cell("llama2_7b", "train_4k", mesh, "mini-multi",

@@ -112,22 +112,22 @@ def test_flash_decode_ragged_int8_parity():
 # ----------------------------------------------------------------------
 
 def _scatter_to_pool(k, v, bs_blk, n_blocks, seed=0):
-    """Lay contiguous (B, S, KV, dh) K/V into a shuffled block pool;
-    returns pools, block tables, and the inverse layout check data."""
+    """Lay contiguous (B, S, KV, dh) K/V into a shuffled head-major
+    block pool (n_blocks, KV, bs, dh); returns pools and block tables."""
     b, s, kv, dh = k.shape
     n_bt = -(-s // bs_blk)
     assert n_blocks >= b * n_bt
     rng = np.random.default_rng(seed)
     perm = rng.permutation(n_blocks)[:b * n_bt].reshape(b, n_bt)
-    kp = np.zeros((n_blocks, bs_blk, kv, dh), np.asarray(k).dtype)
+    kp = np.zeros((n_blocks, kv, bs_blk, dh), np.asarray(k).dtype)
     vp = np.zeros_like(kp)
     pad = n_bt * bs_blk - s
     kc = np.pad(np.asarray(k), ((0, 0), (0, pad), (0, 0), (0, 0)))
     vc = np.pad(np.asarray(v), ((0, 0), (0, pad), (0, 0), (0, 0)))
     for r in range(b):
         for j in range(n_bt):
-            kp[perm[r, j]] = kc[r, j * bs_blk:(j + 1) * bs_blk]
-            vp[perm[r, j]] = vc[r, j * bs_blk:(j + 1) * bs_blk]
+            kp[perm[r, j]] = kc[r, j * bs_blk:(j + 1) * bs_blk].swapaxes(0, 1)
+            vp[perm[r, j]] = vc[r, j * bs_blk:(j + 1) * bs_blk].swapaxes(0, 1)
     return (jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(perm, jnp.int32))
 

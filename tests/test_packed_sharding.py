@@ -69,6 +69,15 @@ def _dec(seed, variant, rank, pattern="2:4"):
     return SLaBDecomposition(w_s, u, v, w_b)
 
 
+def _out_dim(variant, name):
+    """Where d_out sits in a plane: last in the N:M planes and the sign
+    words (the lane axis the kernels tile), first elsewhere."""
+    if name == "b_packed" or (variant.endswith("-nm")
+                              and name.startswith("sparse")):
+        return -1
+    return 0
+
+
 def _pl(variant, rank=None):
     if rank is None:
         rank = 4 if variant in _HAS_LOWRANK else 0
@@ -84,8 +93,9 @@ def _pl(variant, rank=None):
 
 @pytest.mark.parametrize("variant", PACKED_VARIANTS)
 def test_axes_tree_every_variant(variant):
-    """Every stored plane except v leads with packed_out; aux matches
-    the array leaf exactly so tree_map pairs the two structurally."""
+    """Every stored plane except v carries packed_out at its d_out dim;
+    aux matches the array leaf exactly so tree_map pairs the two
+    structurally."""
     pl = _pl(variant)
     ax = packed_linear_axes(pl)
     for name in ("sparse_vals", "sparse_idx", "b_packed", "u"):
@@ -94,7 +104,7 @@ def test_axes_tree_every_variant(variant):
         if arr is not None:
             assert len(a) == arr.ndim, (name, a, arr.shape)
             if name != "u":
-                assert a[0] == "packed_out", (name, a)
+                assert a[_out_dim(variant, name)] == "packed_out", (name, a)
     if pl.v is not None:
         assert ax.v[0] is None           # contracts replicated features
     assert (ax.variant, ax.d_in, ax.d_out, ax.rank) == (
@@ -103,7 +113,9 @@ def test_axes_tree_every_variant(variant):
     st = jax.tree.map(lambda a: a[None], pl)
     ax_st = packed_linear_axes(st, stacked=True)
     if pl.sparse_vals is not None:
-        assert ax_st.sparse_vals[:2] == ("layers", "packed_out")
+        assert ax_st.sparse_vals[0] == "layers"
+        at = _out_dim(variant, "sparse_vals")
+        assert ax_st.sparse_vals[at if at < 0 else at + 1] == "packed_out"
 
 
 def test_u_shards_only_at_rank_threshold():
@@ -121,7 +133,7 @@ def test_u_shards_only_at_rank_threshold():
 @pytest.mark.parametrize("variant", PACKED_VARIANTS)
 def test_planner_spec_every_variant(variant):
     """tree_specs pairs the axes-PackedLinear against the array leaf and
-    row-shards every d_out-leading plane on "model"."""
+    shards every plane's d_out dim on "model"."""
     cfg = configs.get("stablelm_12b", smoke=True)
     pl = _pl(variant, rank=LR_SHARD_RANK if variant in _HAS_LOWRANK
              else None)
@@ -129,8 +141,8 @@ def test_planner_spec_every_variant(variant):
     specs = planner.tree_specs(packed_axes(pl), pl)
     for name in ("sparse_vals", "sparse_idx", "b_packed", "u"):
         if getattr(pl, name) is not None:
-            assert getattr(specs, name)[0] == "model", (name,
-                                                        getattr(specs, name))
+            spec = getattr(specs, name)
+            assert spec[_out_dim(variant, name)] == "model", (name, spec)
     if pl.v is not None:
         assert specs.v == P(None, None)
 
@@ -239,7 +251,8 @@ def test_mesh_decode_parity_two_devices():
     cfg = configs.get("stablelm_12b", smoke=True).with_(
         dtype=jnp.float32, n_layers=2)
     _, packed, _ = synthetic_pruned_packed(cfg, lambda l: 0.5)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 2), ("data", "model"))
     planner = Planner(mesh, cfg)
     placed = jax.device_put(packed, planner.tree_shardings(
         merge_packed_axes(lm.param_axes(cfg), packed), packed))

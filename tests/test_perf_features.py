@@ -71,7 +71,8 @@ def test_sp_attention_numerics_unchanged():
     params, _ = lm.init(cfg, jax.random.PRNGKey(0))
     t = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab)
     base, _ = lm.forward(cfg, params, t)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     with use_mesh(mesh):
         inmesh, _ = jax.jit(lambda p, x: lm.forward(cfg, p, x))(params, t)
     np.testing.assert_allclose(np.asarray(base), np.asarray(inmesh),

@@ -47,14 +47,15 @@ def test_blocks_needed():
 
 
 def test_paged_write_masks_inactive_rows():
-    pool = jnp.zeros((4, 2, 3, 8))       # (n_blocks, bs, KV, dh)
+    pool = jnp.zeros((4, 3, 2, 8))       # (n_blocks, KV, bs, dh)
     new = jnp.ones((3, 8))               # one token's (KV, dh) per row
     out = paged_write(pool, jnp.stack([new, new * 5]),
                       block_ids=jnp.array([1, 2]),
                       offsets=jnp.array([0, 1]),
                       active=jnp.array([True, False]))
     assert float(jnp.sum(jnp.abs(out[2]))) == 0.0   # masked row dropped
-    np.testing.assert_allclose(np.asarray(out[1, 0]), np.asarray(new))
+    np.testing.assert_allclose(np.asarray(out[1, :, 0]), np.asarray(new))
+    assert float(jnp.sum(jnp.abs(out[1, :, 1]))) == 0.0   # offset 0 only
 
 
 def test_init_paged_cache_rejects_cacheless_families():

@@ -149,7 +149,7 @@ def test_signbit_roundtrip(seed, d_in):
     w = _w(seed, 16, d_in)
     b = jnp.where(w >= 0, 1, -1).astype(jnp.int8)
     packed = packing.pack_sign_bits(b)
-    assert packed.shape == (16, d_in // 32)
+    assert packed.shape == (d_in // 32, 16)
     out = packing.unpack_sign_bits(packed, d_in)
     assert bool(jnp.all(out == b))
 

@@ -1,0 +1,105 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler
+for a *described* v5e chip (no chip attached) at StableLM-2-12B widths
+— d_model 5120, d_ff 13824, 8 KV heads x 160, 16-token KV blocks, the
+engine's 8 rows per matmul. This is what interpret-mode tests cannot
+see: block shapes the Mosaic tiling refuses, layouts it cannot lower,
+kernels over the VMEM limit. The topology is described inside a module
+fixture, so only the worker that runs this file loads the TPU
+compiler; everything built from it lives in fixtures or tests too.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.packed_model import PackedLinear, _kernel_blocks
+from repro.kernels import ops
+from repro.kernels.ell import ell_matmul
+from repro.kernels.flash_decode import flash_decode_paged
+
+D_MODEL, D_FF = 5120, 13824
+N_KV, GROUP, D_HEAD, BLOCK = 8, 4, 160, 16
+ROWS, N_BLOCKS, TABLE = 8, 144, 18        # 8 slots x 18 blocks of 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a program compiled for a described chip is written to the
+    # persistent cache but can never be read back without one
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()     # Mosaic, not XLA
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=("bf16", "int8"))
+def test_flash_decode_paged_compiles(one_chip, quant):
+    kv_dt = jnp.int8 if quant else jnp.bfloat16
+    pool = (N_BLOCKS, N_KV, BLOCK, D_HEAD)
+    shapes = [((ROWS, N_KV, GROUP, D_HEAD), jnp.bfloat16),
+              (pool, kv_dt), (pool, kv_dt),
+              ((ROWS, TABLE), jnp.int32), ((ROWS,), jnp.int32)]
+    if quant:
+        shapes += [(pool[:-1], jnp.float32)] * 2
+    _compile(lambda *a: flash_decode_paged(*a, interpret=False),
+             one_chip, *shapes)
+
+
+@pytest.mark.parametrize("d_in,d_out", [(D_MODEL, D_FF), (D_FF, D_MODEL)],
+                         ids=("w_up", "w_down"))
+def test_slab_nm_matmul_compiles(one_chip, d_in, d_out):
+    """The 2:4 SLaB kernel with the blocks packed serving picks."""
+    n, m = 2, 4
+    planes = ((n, d_in // m, d_out), (d_in // 32, d_out))
+    w = PackedLinear(jax.ShapeDtypeStruct(planes[0], jnp.bfloat16),
+                     jax.ShapeDtypeStruct(planes[0], jnp.int8),
+                     jax.ShapeDtypeStruct(planes[1], jnp.uint32),
+                     jax.ShapeDtypeStruct((d_out, 1), jnp.bfloat16),
+                     jax.ShapeDtypeStruct((d_in, 1), jnp.bfloat16),
+                     variant="slab-nm", m_pat=m, d_in=d_in, d_out=d_out,
+                     rank=1)
+    bn, bk = _kernel_blocks(w)
+    assert bn % 128 == 0 and bk % 256 == 0, (bn, bk)
+    _compile(lambda x, vals, idx, bp, u, v: ops.slab_nm_matmul(
+        x, vals, idx, m, bp, u, v, bm=128, bn=bn, bk=bk, interpret=False),
+        one_chip, ((ROWS, d_in), jnp.bfloat16),
+        (planes[0], jnp.bfloat16), (planes[0], jnp.int8),
+        (planes[1], jnp.uint32), ((d_out, 1), jnp.bfloat16),
+        ((d_in, 1), jnp.bfloat16))
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "ELL does not lower to Mosaic: the fori_loop over K_max chunks is an "
+    "unimplemented dynamic_slice, and static chunks make the 3-D "
+    "jnp.take gather a shape mismatch"))
+def test_ell_matmul_does_not_lower(one_chip):
+    k_max = D_MODEL // 2                  # 50% unstructured rows
+    _compile(lambda x, vals, idx: ell_matmul(x, vals, idx, bm=ROWS,
+                                             bn=256, interpret=False),
+             one_chip, ((ROWS, D_MODEL), jnp.bfloat16),
+             ((D_FF, k_max), jnp.bfloat16), ((D_FF, k_max), jnp.uint16))
