@@ -55,6 +55,7 @@ tests/examples at smoke scale and is the TPU serving configuration.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import types
@@ -67,7 +68,7 @@ import jax.numpy as jnp
 from repro.core.packing import (ell_pack, ell_row_nnz_max, ell_wins_bytes,
                                 pack_nm, pack_sign_bits)
 from repro.core.slab import SLaBDecomposition
-from repro.models.common import is_axes_leaf, tap_record
+from repro.models.common import is_axes_leaf, scope, tap_record
 
 Array = jax.Array
 
@@ -772,12 +773,14 @@ def linear(x: Array, w, tap: Optional[str] = None) -> Array:
     packed fused kernel. ``tap`` names this linear for activation
     capture (models.common.tap_capture): when a capture is active the
     exact input ``x`` is reported under the current tap scope before
-    the matmul runs; otherwise it's a no-op."""
+    the matmul runs; otherwise it's a no-op. The matmul's ops carry the
+    named scope ``tap``."""
     if tap is not None:
         tap_record(tap, x)
-    if isinstance(w, PackedLinear):
-        return packed_matmul(x, w)
-    return x @ w
+    with scope(tap) if tap is not None else contextlib.nullcontext():
+        if isinstance(w, PackedLinear):
+            return packed_matmul(x, w)
+        return x @ w
 
 
 # ------------------------------------------------------------------

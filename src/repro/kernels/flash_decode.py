@@ -129,6 +129,7 @@ def flash_decode_paged(q: Array, k_pool: Array, v_pool: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, kv, g, dh), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(block_tables, lengths, *operands)
 
 

@@ -444,6 +444,26 @@ def _serve_engine(args, res: Served, planner, requests) -> None:
     lat = m['per_token_latency']
     print(f"  per-token p50/p95/p99: {lat['p50'] * 1e3:.1f}/"
           f"{lat['p95'] * 1e3:.1f}/{lat['p99'] * 1e3:.1f}ms")
+    _report_obs(eng.obs)
+
+
+def _report_obs(rec) -> None:
+    """The engine's own record (``serving/obs.py``): mean time per step
+    in each span, TTFT split into queue wait and prefill, counters."""
+    by_name: dict = {}
+    for name, t0, t1, _, _ in rec.spans:
+        by_name.setdefault(name, []).append((t1 - t0) / 1e6)
+    if by_name:
+        print("  observability, mean ms per step: " + ", ".join(
+            f"{n} {np.mean(v):.2f}" for n, v in by_name.items()))
+    done = [r for r in rec.requests if r["first_token"] is not None]
+    if done:
+        wait = [r["admitted"] - r["arrival"] for r in done]
+        pre = [r["first_token"] - r["admitted"] for r in done]
+        print(f"  ttft split p50: queue wait {np.median(wait):.3f}s + "
+              f"prefill {np.median(pre):.3f}s")
+    print("  counters: " + ", ".join(
+        f"{k}={v}" for k, v in sorted(rec.counters.items())))
 
 
 def main():

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packed_model import linear
-from repro.models.common import ArchConfig, dense_init, rotate
+from repro.models.common import ArchConfig, dense_init, rotate, scope
 from repro.runtime.meshctx import hint
 
 Array = jax.Array
@@ -248,29 +248,33 @@ def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
 
     bs_blk = pool.block_size
     n_bt = block_tables.shape[1]
-    # physical write slot; clamp shields idle rows with stale lengths
-    # (their write is dropped by `active` anyway)
-    blk = jnp.take_along_axis(
-        block_tables, jnp.clip(lengths // bs_blk, 0, n_bt - 1)[:, None],
-        axis=1)[:, 0]
-    off = lengths % bs_blk
-    if cfg.kv_quant:
-        k_q, k_s = _quantize_token(k_new)
-        v_q, v_s = _quantize_token(v_new)
-        pool = pool._replace(
-            k=paged_write(pool.k, k_q[:, 0], blk, off, active),
-            v=paged_write(pool.v, v_q[:, 0], blk, off, active),
-            k_scale=paged_write(pool.k_scale, k_s[:, 0], blk, off, active),
-            v_scale=paged_write(pool.v_scale, v_s[:, 0], blk, off, active))
-    else:
-        pool = pool._replace(
-            k=paged_write(pool.k, k_new[:, 0], blk, off, active),
-            v=paged_write(pool.v, v_new[:, 0], blk, off, active))
+    with scope("kv_write"):
+        # physical write slot; clamp shields idle rows with stale
+        # lengths (their write is dropped by `active` anyway)
+        blk = jnp.take_along_axis(
+            block_tables, jnp.clip(lengths // bs_blk, 0, n_bt - 1)[:, None],
+            axis=1)[:, 0]
+        off = lengths % bs_blk
+        if cfg.kv_quant:
+            k_q, k_s = _quantize_token(k_new)
+            v_q, v_s = _quantize_token(v_new)
+            pool = pool._replace(
+                k=paged_write(pool.k, k_q[:, 0], blk, off, active),
+                v=paged_write(pool.v, v_q[:, 0], blk, off, active),
+                k_scale=paged_write(pool.k_scale, k_s[:, 0], blk, off,
+                                    active),
+                v_scale=paged_write(pool.v_scale, v_s[:, 0], blk, off,
+                                    active))
+        else:
+            pool = pool._replace(
+                k=paged_write(pool.k, k_new[:, 0], blk, off, active),
+                v=paged_write(pool.v, v_new[:, 0], blk, off, active))
 
-    qg = q[:, 0].reshape(b, kv, g, dh) * (dh ** -0.5)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    out = ops.flash_decode_paged_attention(
-        qg, pool.k, pool.v, block_tables, att_len,
-        pool.k_scale, pool.v_scale)
-    out = out.reshape(b, 1, cfg.d_q).astype(x.dtype)
+    with scope("paged_attn"):
+        qg = q[:, 0].reshape(b, kv, g, dh) * (dh ** -0.5)
+        att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
+        out = ops.flash_decode_paged_attention(
+            qg, pool.k, pool.v, block_tables, att_len,
+            pool.k_scale, pool.v_scale)
+        out = out.reshape(b, 1, cfg.d_q).astype(x.dtype)
     return linear(out, p["wo"], tap="wo"), pool

@@ -53,6 +53,25 @@ Array = jax.Array
 # list check) when no capture is active, so instrumented forwards cost
 # nothing in production, and the scope/record calls inside scanned layer
 # bodies only ever execute at trace time.
+#
+# The same names label the device work: ``tap_scope`` and a tapped
+# ``linear`` also enter ``jax.named_scope`` (through ``scope``), so the
+# compiled ops of ``attn.wq`` carry ``attn/wq`` in their op names;
+# parts of a step that own no linear (the layer scan, the KV write,
+# the head) enter ``scope`` directly.
+
+#: every name ``scope`` has entered: how the serving engine tells the
+#: program's scopes from the components JAX adds to an op's name
+#: (``while``, ``body``, ``closed_call``, ``jit(f)``).
+SCOPE_NAMES: set = set()
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """``jax.named_scope(name)``, recorded in ``SCOPE_NAMES``."""
+    SCOPE_NAMES.add(name)
+    with jax.named_scope(name):
+        yield
 
 _tap_state = threading.local()
 
@@ -187,11 +206,13 @@ def tap_capture(hessian: bool = False,
 
 @contextlib.contextmanager
 def tap_scope(prefix: str):
-    """Push a name component: taps inside record as '<prefix>.<leaf>'."""
+    """Push a name component: taps inside record as '<prefix>.<leaf>',
+    and the ops traced inside carry the named scope ``prefix``."""
     stack = _tap_prefix()
     stack.append(prefix)
     try:
-        yield
+        with scope(prefix):
+            yield
     finally:
         stack.pop()
 

@@ -34,7 +34,7 @@ from repro.models import mlp as mlp_lib
 from repro.models import moe as moe_lib
 from repro.models.common import (ArchConfig, embed_init, dense_init,
                                  is_axes_leaf, positions_for, rms_norm,
-                                 softmax_xent, tap_scope)
+                                 scope, softmax_xent, tap_scope)
 
 Array = jax.Array
 AUX_LOSS_WEIGHT = 0.01
@@ -549,7 +549,8 @@ def paged_decode_step(cfg: ArchConfig, params: dict, paged,
         raise ValueError(f"paged decode: unsupported family {cfg.family!r}")
     r = token.shape[0]
     positions = positions_for(cfg, r, 1, offset=lengths[:, None])
-    h = embed_inputs(cfg, params, token)
+    with scope("embed"):
+        h = embed_inputs(cfg, params, token)
     h = hint(h, DP, None, None)
 
     stacked = params["layers"]
@@ -566,16 +567,18 @@ def paged_decode_step(cfg: ArchConfig, params: dict, paged,
 
     pool_parts = []
     for lo, hi in segments:
-        h, pool_new = _seg_scan(
-            body, h,
-            (layer_slice_range(stacked, lo, hi),
-             _slice_layers(paged, lo, hi, cfg.n_layers),
-             jnp.arange(lo, hi)), hi - lo)
+        with scope("layer_scan"):
+            h, pool_new = _seg_scan(
+                body, h,
+                (layer_slice_range(stacked, lo, hi),
+                 _slice_layers(paged, lo, hi, cfg.n_layers),
+                 jnp.arange(lo, hi)), hi - lo)
         pool_parts.append(pool_new)
     new_paged = _cat_parts(pool_parts)
 
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return unembed(cfg, params, h), new_paged
+    with scope("head"):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        return unembed(cfg, params, h), new_paged
 
 
 def prefill(cfg: ArchConfig, params: dict, inputs: Array,

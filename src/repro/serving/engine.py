@@ -18,8 +18,9 @@ iteration of ``run``:
      layer scan + ``flash_decode_paged`` block-table kernel), with
      per-row validity masks — shapes never depend on which requests are
      live, so there are exactly two compilations (C and 1) for the
-     whole serving lifetime. The step also reduces a per-row
-     finite-logits flag (one ``jnp.isfinite`` all-reduce per position);
+     whole serving lifetime (``obs.counters["engine.builds"]`` counts
+     them). The step also reduces a per-row finite-logits flag (one
+     ``jnp.isfinite`` all-reduce per position);
   5. quarantine rows that went non-finite (retry once via the
      recompute-replay eviction path, then fail them — neighbors in the
      fused batch never see it), sample greedily at each surviving
@@ -36,6 +37,15 @@ Open-loop traces: requests carry ``arrival`` stamps; ``clock="steps"``
 replays them against the engine-step counter (deterministic — tests),
 ``clock="wall"`` against wall time (benchmarks). The engine never
 blocks on stragglers: batch composition changes every step.
+
+Observability: ``engine.obs`` (``serving/obs.py``) records one
+``engine.iteration`` span per step with its parts as children
+(``sched.expire``, ``sched.admit``, ``sched.plan``, ``engine.h2d``,
+``engine.dispatch``, ``engine.device_wait``, ``engine.commit``), each
+request's arrival / admission / first token / finish, the scheduler's
+work counters and the step programs built; the device ops of each
+program carry named scopes (``embed``, ``layer_scan``, ``attn/wq``,
+``kv_write``, ``paged_attn``, ``head``, ``finite_check``, ``sample``).
 """
 from __future__ import annotations
 
@@ -49,7 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import lm
-from repro.models.common import ArchConfig
+from repro.models.common import SCOPE_NAMES, ArchConfig, scope
+from repro.serving import obs
 from repro.serving.faults import FaultPlan
 from repro.serving.paged_cache import (PagedKVCache, init_paged_cache,
                                        paged_cache_axes, table_width)
@@ -95,12 +106,14 @@ class Engine:
         self.params = params
         self.ecfg = ecfg
         self.mesh = mesh
+        self.obs = obs.for_engine()
         self.sched = Scheduler(ecfg.n_slots, ecfg.n_blocks,
                                ecfg.block_size, ecfg.max_len,
                                ecfg.prefill_chunk,
                                max_waiting=ecfg.max_waiting,
                                shed=ecfg.shed,
-                               max_evictions=ecfg.max_evictions)
+                               max_evictions=ecfg.max_evictions,
+                               obs=self.obs)
         self.paged = init_paged_cache(cfg, ecfg.n_blocks, ecfg.block_size)
         self._pool_sharding = None
         if planner is not None:
@@ -138,12 +151,14 @@ class Engine:
                 active = t < n_valid
                 logits, paged = lm.paged_decode_step(
                     cfg, params, paged, tables, lens, tok[:, None], active)
-                logits = jnp.where(force_nan[:, None, None], jnp.nan,
-                                   logits)
-                ok = ok & (jnp.all(jnp.isfinite(logits[:, 0]), axis=-1)
-                           | ~active)
-                nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
-                last = jnp.where(t == n_valid - 1, nxt, last)
+                with scope("finite_check"):
+                    logits = jnp.where(force_nan[:, None, None], jnp.nan,
+                                       logits)
+                    ok = ok & (jnp.all(jnp.isfinite(logits[:, 0]), axis=-1)
+                               | ~active)
+                with scope("sample"):
+                    nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+                    last = jnp.where(t == n_valid - 1, nxt, last)
                 return (paged, lens + active, last, ok), None
 
             xs = (jnp.moveaxis(tokens, 1, 0), jnp.arange(c))
@@ -168,7 +183,8 @@ class Engine:
     def compile(self) -> float:
         """Compile both step programs (chunk C and the C=1 decode step)
         ahead of serving, so that no request waits on the compiler.
-        Returns the seconds spent; ``run`` compiles lazily otherwise."""
+        Returns the seconds spent; ``run`` compiles lazily otherwise.
+        Each program's ops are mapped to their scopes in ``obs.scopes``."""
         from repro.runtime.meshctx import use_mesh
         t0 = time.monotonic()
         r = self.sched.n_slots
@@ -180,6 +196,8 @@ class Engine:
                                    np.zeros((r,), bool))
             with use_mesh(self.mesh):
                 self._steps[c] = self._step_fn(c).lower(*args).compile()
+            self.obs.count("engine.builds")
+            self.obs.add_scopes(self._steps[c].as_text(), SCOPE_NAMES)
         return time.monotonic() - t0
 
     def _run_step(self, tokens: np.ndarray, n_valid: np.ndarray,
@@ -188,10 +206,13 @@ class Engine:
         c = tokens.shape[1]
         if c not in self._steps:
             self._steps[c] = self._step_fn(c)
-        with use_mesh(self.mesh):
-            self.paged, last, ok = self._steps[c](
-                *self._step_args(tokens, n_valid, force_nan))
-        return np.asarray(last), np.asarray(ok)
+            self.obs.count("engine.builds")
+        with self.obs.span("engine.h2d"):
+            args = self._step_args(tokens, n_valid, force_nan)
+        with use_mesh(self.mesh), self.obs.span("engine.dispatch"):
+            self.paged, last, ok = self._steps[c](*args)
+        with self.obs.span("engine.device_wait"):
+            return np.asarray(last), np.asarray(ok)
 
     # -- fault plumbing ----------------------------------------------------
 
@@ -249,6 +270,49 @@ class Engine:
                 self.sched._finalize(q.pop(0), status, error=error,
                                      now=now)
 
+    def _idle(self, now: float, clock: str,
+              faults: Optional[FaultPlan], fired: set,
+              idle_guard: int) -> bool:
+        """An iteration with nothing to run: wait for the next arrival
+        (or heal a stall). Returns True when the run is over."""
+        if not self.sched.has_work():
+            return True                  # expiry drained the trace
+        nxt = self.sched.next_arrival()
+        heal = (faults is not None
+                and faults.has_restore_after(self.n_steps))
+        if (heal and clock == "wall" and nxt is None
+                and not self.sched.slots):
+            # dead idle on the wall clock never advances n_steps, so a
+            # step-indexed restore would never fire — fast-forward it
+            # instead of sleeping on it
+            for i, ev in enumerate(faults.events):
+                if ev.kind == "pool_restore" and i not in fired:
+                    fired.add(i)
+                    self.sched.alloc.release(
+                        ev.n_blocks if ev.n_blocks else None)
+            return False
+        if (nxt is None and not self.sched.slots
+                and self.sched.waiting and not heal):
+            # permanent stall: nothing runs, nothing arrives, no
+            # scheduled restore — fail the blocked head with the block
+            # accounting, keep serving the rest
+            diag = self.sched.diagnose_stall() or (
+                "admission stalled with free blocks")
+            self.sched._finalize(self.sched.waiting.pop(0), "failed",
+                                 error=diag, now=now)
+            return False
+        if idle_guard > IDLE_LIMIT:
+            diag = self.sched.diagnose_stall()
+            self._finalize_unfinished(
+                "failed", f"idle-loop livelock after {IDLE_LIMIT} "
+                f"iterations" + (f": {diag}" if diag else ""), now)
+            return True
+        if clock == "steps":
+            self.n_steps += 1
+        else:
+            time.sleep(min(1e-3, max(nxt - now, 0.0) if nxt else 1e-3))
+        return False
+
     def run(self, requests: Sequence[Request], clock: str = "steps",
             max_steps: Optional[int] = None,
             faults: Optional[FaultPlan] = None) -> List[Request]:
@@ -263,73 +327,45 @@ class Engine:
             self.sched.submit(req)       # unservable -> status rejected
         injected: List[Request] = []
         fired: set = set()
-        t0 = time.monotonic()
+        t0 = self.obs.clock0_ns = time.perf_counter_ns()
         idle_guard = 0
         while self.sched.has_work():
+            t = time.perf_counter_ns()
             now = (float(self.n_steps) if clock == "steps"
-                   else time.monotonic() - t0)
-            self._fire_faults(faults, fired, now, injected)
-            self.sched.expire(now)
-            self.sched.admit(now)
-            plan = self.sched.plan_step()
-            if plan is None:
-                if not self.sched.has_work():
-                    break                # expiry drained the trace
-                nxt = self.sched.next_arrival()
-                idle_guard += 1
-                heal = (faults is not None
-                        and faults.has_restore_after(self.n_steps))
-                if (heal and clock == "wall" and nxt is None
-                        and not self.sched.slots):
-                    # dead idle on the wall clock never advances
-                    # n_steps, so a step-indexed restore would never
-                    # fire — fast-forward it instead of sleeping on it
-                    for i, ev in enumerate(faults.events):
-                        if ev.kind == "pool_restore" and i not in fired:
-                            fired.add(i)
-                            self.sched.alloc.release(
-                                ev.n_blocks if ev.n_blocks else None)
+                   else (t - t0) / 1e9)
+            self.obs.step = self.n_steps
+            with self.obs.span("engine.iteration", start=t):
+                self._fire_faults(faults, fired, now, injected)
+                with self.obs.span("sched.expire"):
+                    self.sched.expire(now)
+                with self.obs.span("sched.admit"):
+                    self.sched.admit(now)
+                with self.obs.span("sched.plan"):
+                    plan = self.sched.plan_step()
+                if plan is None:
+                    self.obs.drop()          # an idle pass is no step
+                    idle_guard += 1
+                    if self._idle(now, clock, faults, fired, idle_guard):
+                        break
                     continue
-                if (nxt is None and not self.sched.slots
-                        and self.sched.waiting and not heal):
-                    # permanent stall: nothing runs, nothing arrives,
-                    # no scheduled restore — fail the blocked head with
-                    # the block accounting, keep serving the rest
-                    diag = self.sched.diagnose_stall() or (
-                        "admission stalled with free blocks")
-                    self.sched._finalize(self.sched.waiting.pop(0),
-                                         "failed", error=diag, now=now)
-                    continue
-                if idle_guard > IDLE_LIMIT:
-                    diag = self.sched.diagnose_stall()
+                idle_guard = 0
+                tokens, n_valid, _ = plan
+                force_nan = np.zeros((self.sched.n_slots,), bool)
+                if faults is not None:
+                    for row in faults.nan_rows(self.n_steps):
+                        force_nan[row] = True
+                last, ok = self._run_step(tokens, n_valid, force_nan)
+                self.n_steps += 1
+                emit_t = (float(self.n_steps) if clock == "steps"
+                          else (time.perf_counter_ns() - t0) / 1e9)
+                with self.obs.span("engine.commit"):
+                    self._quarantine_nonfinite(n_valid, ok, emit_t)
+                    self.sched.commit_step(n_valid, last, emit_t)
+                if max_steps is not None and self.n_steps >= max_steps:
                     self._finalize_unfinished(
-                        "failed", f"idle-loop livelock after "
-                        f"{IDLE_LIMIT} iterations"
-                        + (f": {diag}" if diag else ""), now)
+                        "timeout", f"max_steps={max_steps} exhausted",
+                        emit_t)
                     break
-                if clock == "steps":
-                    self.n_steps += 1
-                else:
-                    time.sleep(min(1e-3, max(nxt - now, 0.0) if nxt
-                                   else 1e-3))
-                continue
-            idle_guard = 0
-            tokens, n_valid, _ = plan
-            force_nan = np.zeros((self.sched.n_slots,), bool)
-            if faults is not None:
-                for row in faults.nan_rows(self.n_steps):
-                    force_nan[row] = True
-            last, ok = self._run_step(tokens, n_valid, force_nan)
-            self.n_steps += 1
-            emit_t = (float(self.n_steps) if clock == "steps"
-                      else time.monotonic() - t0)
-            self._quarantine_nonfinite(n_valid, ok, emit_t)
-            self.sched.commit_step(n_valid, last, emit_t)
-            if max_steps is not None and self.n_steps >= max_steps:
-                self._finalize_unfinished(
-                    "timeout", f"max_steps={max_steps} exhausted",
-                    emit_t)
-                break
         # faults are scoped to the run: any still-reserved blocks come
         # back so the pool-leak invariant (n_free == n_blocks once all
         # streams are terminal) holds at trace end

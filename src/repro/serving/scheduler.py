@@ -51,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.serving.obs import Recorder
 from repro.serving.paged_cache import (BlockAllocator, blocks_needed,
                                        table_width)
 
@@ -75,6 +76,8 @@ class Request:
     error: Optional[str] = None         # terminal diagnostic (failures)
     out: List[int] = dataclasses.field(default_factory=list)
     ttft: Optional[float] = None        # first-token time - arrival
+    admitted: Optional[float] = None    # first admission (a replay keeps it)
+    first_token: Optional[float] = None
     finish: Optional[float] = None
     token_times: List[float] = dataclasses.field(default_factory=list)
     n_evictions: int = 0
@@ -127,7 +130,7 @@ class Scheduler:
     def __init__(self, n_slots: int, n_blocks: int, block_size: int,
                  max_len: int, prefill_chunk: int = 8,
                  max_waiting: Optional[int] = None, shed: str = "reject",
-                 max_evictions: int = 8):
+                 max_evictions: int = 8, obs: Optional[Recorder] = None):
         if n_slots < 1 or n_blocks < 1 or prefill_chunk < 1:
             raise ValueError((n_slots, n_blocks, prefill_chunk))
         if shed not in ("reject", "evict-oldest-waiting"):
@@ -150,6 +153,7 @@ class Scheduler:
         self.lengths = np.zeros((n_slots,), np.int32)
         self._admit_seq = 0
         self.n_evictions = 0
+        self.obs = obs if obs is not None else Recorder()
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -161,6 +165,7 @@ class Scheduler:
         req.error = error
         if now is not None:
             req.finish = now
+        self.obs.request_done(req)
         return req
 
     # -- submission / admission ------------------------------------------
@@ -248,6 +253,8 @@ class Scheduler:
                 break
             req = self.waiting.pop(0)
             req.status = "running"
+            if req.admitted is None:
+                req.admitted = now
             self.slots[row] = _Slot(req=req, blocks=[], n_prefilled=0,
                                     admit_seq=self._admit_seq,
                                     phase="prefill")
@@ -377,6 +384,13 @@ class Scheduler:
                 n_valid[row] = 1
         if not n_valid.any():
             return None
+        # the step's work: (row, position) pairs and the cached tokens
+        # they attend (a row of length L feeding n attends L+1 .. L+n)
+        nv = n_valid.astype(np.int64)
+        self.obs.count(f"sched.steps.c{c}")
+        self.obs.count("sched.valid_pairs", int(nv.sum()))
+        self.obs.count("sched.ctx_tokens",
+                       int(nv @ (2 * self.lengths + nv + 1)) // 2)
         return tokens, n_valid, any_prefill
 
     def commit_step(self, n_valid: np.ndarray, sampled: np.ndarray,
@@ -400,6 +414,7 @@ class Scheduler:
             req = slot.req
             if req.ttft is None:
                 req.ttft = now - req.arrival
+                req.first_token = now
             req.out.append(tok)
             req.token_times.append(now)
             slot.next_token = tok

@@ -10,6 +10,7 @@ fixture, so only the worker that runs this file loads the TPU
 compiler; everything built from it lives in fixtures or tests too.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +67,11 @@ def test_flash_decode_paged_compiles(one_chip, quant):
               ((ROWS, TABLE), jnp.int32), ((ROWS,), jnp.int32)]
     if quant:
         shapes += [(pool[:-1], jnp.float32)] * 2
-    _compile(lambda *a: flash_decode_paged(*a, interpret=False),
-             one_chip, *shapes)
+    compiled = _compile(lambda *a: flash_decode_paged(*a, interpret=False),
+                        one_chip, *shapes)
+    # the device trace names the kernel's op after the pallas_call
+    assert re.search(r"^\s*(ROOT )?%flash_decode_paged(\.\d+)? = ",
+                     compiled.as_text(), re.M)
 
 
 @pytest.mark.parametrize("d_in,d_out", [(D_MODEL, D_FF), (D_FF, D_MODEL)],
