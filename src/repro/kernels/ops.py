@@ -341,13 +341,14 @@ def binlr_g(x: Array, b_packed: Array, u: Array, v: Array,
 
 
 def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
-                                 block_tables: Array, lengths: Array,
+                                 block_tables: Array, lengths: Array, layer,
                                  k_scale: Optional[Array] = None,
                                  v_scale: Optional[Array] = None,
                                  interpret: Optional[bool] = None) -> Array:
-    """Paged (block-table) grouped-query decode attention. q (R, KV, G,
-    dh) pre-scaled; k_pool/v_pool (n_blocks, KV, bs, dh);
-    block_tables (R, n_bt); lengths (R,) — zero-length rows return 0.
+    """Paged (block-table) grouped-query decode attention over one layer
+    of the stacked pool. q (R, KV, G, dh) pre-scaled; k_pool/v_pool
+    (L, n_blocks, KV, bs, dh); block_tables (R, n_bt); lengths (R,) —
+    zero-length rows return 0; layer an int32 scalar.
 
     Under a multi-device mesh the kernel runs inside ``shard_map`` (the
     chip's compiler cannot partition a Pallas kernel): each device
@@ -357,18 +358,19 @@ def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
     from repro.runtime.meshctx import current_mesh
     interpret = _on_cpu() if interpret is None else interpret
     fn = functools.partial(flash_decode_paged, interpret=interpret)
-    args = (q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale)
+    args = (q, k_pool, v_pool, block_tables, lengths, layer, k_scale,
+            v_scale)
     mesh = current_mesh()
     if mesh is None or mesh.size == 1:
         return fn(*args)
     from jax.sharding import PartitionSpec as P
     n_model = dict(mesh.shape).get("model", 1)
-    heads = P(None, "model" if n_model > 1 and q.shape[1] % n_model == 0
-              else None)
-    scale = heads if k_scale is not None else None
+    ax = "model" if n_model > 1 and q.shape[1] % n_model == 0 else None
+    heads, pool = P(None, ax), P(None, None, ax)
+    scale = pool if k_scale is not None else None
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(heads, heads, heads, P(), P(), scale, scale),
+        in_specs=(heads, pool, pool, P(), P(), P(), scale, scale),
         out_specs=heads, check_vma=False)(*args)
 
 

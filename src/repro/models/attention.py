@@ -222,19 +222,20 @@ def decode_attention(
 
 
 def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
-                           block_tables: Array, lengths: Array,
+                           layer, block_tables: Array, lengths: Array,
                            positions: Array, active: Array):
-    """One-token decode against a paged KV cache (one layer's pool).
+    """One-token decode of layer ``layer`` against the paged KV cache.
 
-    x (R, 1, D); pool a single-layer ``serving.paged_cache.PagedKVCache``
-    slice (k/v (n_blocks, KV, bs, dh)); block_tables (R, n_bt) int32;
-    lengths (R,) tokens already cached per row (also the write
-    position); active (R,) bool — inactive rows write nothing and
-    return zeros. Returns (out (R, 1, D), updated pool).
+    x (R, 1, D); pool the stacked ``serving.paged_cache.PagedKVCache``
+    (k/v (L, n_blocks, KV, bs, dh)); layer an int32 scalar;
+    block_tables (R, n_bt) int32; lengths (R,) tokens already cached per
+    row (also the write position); active (R,) bool — inactive rows
+    write nothing and return zeros. Returns (out (R, 1, D), updated
+    pool).
 
-    The new token's K/V scatter into block ``block_tables[r, len//bs]``
-    at offset ``len % bs``; attention then reads the whole stream
-    through the block table via the ``flash_decode_paged`` kernel
+    The new token's K/V scatter into ``[layer, block_tables[r, len//bs],
+    :, len % bs]`` of the stacked pool; attention then reads the whole
+    stream through the block table via the ``flash_decode_paged`` kernel
     (scalar-prefetched indices), int8 path included."""
     from repro.kernels import ops
     from repro.serving.paged_cache import paged_write
@@ -258,23 +259,18 @@ def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
         if cfg.kv_quant:
             k_q, k_s = _quantize_token(k_new)
             v_q, v_s = _quantize_token(v_new)
-            pool = pool._replace(
-                k=paged_write(pool.k, k_q[:, 0], blk, off, active),
-                v=paged_write(pool.v, v_q[:, 0], blk, off, active),
-                k_scale=paged_write(pool.k_scale, k_s[:, 0], blk, off,
-                                    active),
-                v_scale=paged_write(pool.v_scale, v_s[:, 0], blk, off,
-                                    active))
+            news = (k_q[:, 0], v_q[:, 0], k_s[:, 0], v_s[:, 0])
         else:
-            pool = pool._replace(
-                k=paged_write(pool.k, k_new[:, 0], blk, off, active),
-                v=paged_write(pool.v, v_new[:, 0], blk, off, active))
+            news = (k_new[:, 0], v_new[:, 0])
+        pool = pool._replace(**{
+            f: paged_write(getattr(pool, f), new, layer, blk, off, active)
+            for f, new in zip(pool._fields, news)})
 
     with scope("paged_attn"):
         qg = q[:, 0].reshape(b, kv, g, dh) * (dh ** -0.5)
         att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
         out = ops.flash_decode_paged_attention(
-            qg, pool.k, pool.v, block_tables, att_len,
+            qg, pool.k, pool.v, block_tables, att_len, layer,
             pool.k_scale, pool.v_scale)
         out = out.reshape(b, 1, cfg.d_q).astype(x.dtype)
     return linear(out, p["wo"], tap="wo"), pool

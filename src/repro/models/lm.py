@@ -509,12 +509,12 @@ def decode_step(cfg: ArchConfig, params: dict, cache: LayerCache,
 
 
 def _layer_decode_paged(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
-                        h: Array, pool_l, block_tables: Array,
+                        h: Array, pool, block_tables: Array,
                         lengths: Array, positions: Array, active: Array):
     with tap_scope("attn"):
-        a, pool_l = attn_lib.paged_decode_attention(
+        a, pool = attn_lib.paged_decode_attention(
             cfg, lp["attn"], rms_norm(h, lp["attn_norm"], cfg.norm_eps),
-            pool_l, block_tables, lengths, positions, active)
+            pool, idx, block_tables, lengths, positions, active)
     h = h + a
     hin = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
     if cfg.family == "moe":
@@ -523,7 +523,7 @@ def _layer_decode_paged(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
     else:
         with tap_scope("mlp"):
             y = mlp_lib.mlp(cfg, lp["mlp"], hin)
-    return h + y, pool_l
+    return h + y, pool
 
 
 def paged_decode_step(cfg: ArchConfig, params: dict, paged,
@@ -541,9 +541,11 @@ def paged_decode_step(cfg: ArchConfig, params: dict, paged,
 
     The layer loop reuses the segmented-scan machinery of
     ``decode_step`` — heterogeneous packed stacks trace O(#segments)
-    bodies — with the per-layer pool slices riding the scan xs exactly
-    like the dense KV cache does. KV-attention families only (the
-    engine gates SSM/hybrid out at construction)."""
+    bodies. The whole stacked pool rides the scan carry, so each layer
+    writes its token in place at its layer index and the kernel reads
+    it there: no layer's pool is sliced out or stacked back.
+    KV-attention families only (the engine gates SSM/hybrid out at
+    construction)."""
     from repro.runtime.meshctx import DP, hint
     if cfg.family in ("ssm", "hybrid", "audio"):
         raise ValueError(f"paged decode: unsupported family {cfg.family!r}")
@@ -557,28 +559,26 @@ def paged_decode_step(cfg: ArchConfig, params: dict, paged,
     if segments is None:
         segments = segment_runs(stacked, cfg.n_layers)
 
-    def body(h, xs):
-        lp, pool_l, idx = xs
+    def body(carry, xs):
+        h, pool = carry
+        lp, idx = xs
         h = hint(h, DP, None, None)
-        h, pool_new = _layer_decode_paged(cfg, params, lp, idx, h, pool_l,
-                                          block_tables, lengths, positions,
-                                          active)
-        return h, pool_new
+        return _layer_decode_paged(cfg, params, lp, idx, h, pool,
+                                   block_tables, lengths, positions,
+                                   active), None
 
-    pool_parts = []
+    carry = (h, paged)
     for lo, hi in segments:
         with scope("layer_scan"):
-            h, pool_new = _seg_scan(
-                body, h,
-                (layer_slice_range(stacked, lo, hi),
-                 _slice_layers(paged, lo, hi, cfg.n_layers),
-                 jnp.arange(lo, hi)), hi - lo)
-        pool_parts.append(pool_new)
-    new_paged = _cat_parts(pool_parts)
+            carry, _ = _seg_scan(
+                body, carry,
+                (layer_slice_range(stacked, lo, hi), jnp.arange(lo, hi)),
+                hi - lo)
+    h, paged = carry
 
     with scope("head"):
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-        return unembed(cfg, params, h), new_paged
+        return unembed(cfg, params, h), paged
 
 
 def prefill(cfg: ArchConfig, params: dict, inputs: Array,

@@ -16,7 +16,8 @@ iteration of ``run``:
   4. run ONE jitted step: a ``lax.scan`` over the chunk's token
      positions, each position a ``lm.paged_decode_step`` (the segmented
      layer scan + ``flash_decode_paged`` block-table kernel), with
-     per-row validity masks — shapes never depend on which requests are
+     per-row validity masks; the pool is donated to the step and
+     written in place — shapes never depend on which requests are
      live, so there are exactly two compilations (C and 1) for the
      whole serving lifetime (``obs.counters["engine.builds"]`` counts
      them). The step also reduces a per-row finite-logits flag (one
@@ -50,6 +51,7 @@ program carry named scopes (``embed``, ``layer_scan``, ``attn/wq``,
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from collections import Counter
 from typing import Dict, List, Optional, Sequence
@@ -71,6 +73,21 @@ Array = jax.Array
 #: graceful backstop for pathological admit/evict cycles the stall
 #: diagnosis cannot prove permanent — finalizes instead of raising.
 IDLE_LIMIT = 100_000
+
+# One entry of a compiled module's ``input_output_alias``: the output's
+# tuple index, then the parameter whose buffer it is written into.
+_ALIAS = re.compile(r"\{(\d+)\}: \(\d+, \{[^}]*\}, \w+-alias\)")
+
+
+def pool_aliases(hlo_text: str, n_pool: int) -> List[int]:
+    """The step outputs among the pool's ``n_pool`` leaves (the first
+    outputs) that a compiled step program writes into a donated input
+    buffer, read from its ``as_text()``."""
+    header = hlo_text.split("\n", 1)[0]
+    if "input_output_alias=" not in header:
+        return []
+    return sorted(int(out) for out in _ALIAS.findall(header)
+                  if int(out) < n_pool)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,7 +153,9 @@ class Engine:
         numerical guard; ``force_nan`` poisons chosen rows — the
         fault-injection hook, all zeros in normal serving). The weights
         are an argument, never a closure: closed-over arrays would be
-        compiled into the program as constants."""
+        compiled into the program as constants. The pool is donated:
+        the step writes it in place, and the engine keeps only the pool
+        that comes out."""
         cfg, pool_sharding = self.cfg, self._pool_sharding
 
         def step(params, paged: PagedKVCache, tables: Array,
@@ -170,7 +189,7 @@ class Engine:
                                                          pool_sharding)
             return paged, last, ok
 
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=(1,))
 
     def _step_args(self, tokens: np.ndarray, n_valid: np.ndarray,
                    force_nan: np.ndarray):
@@ -184,7 +203,10 @@ class Engine:
         """Compile both step programs (chunk C and the C=1 decode step)
         ahead of serving, so that no request waits on the compiler.
         Returns the seconds spent; ``run`` compiles lazily otherwise.
-        Each program's ops are mapped to their scopes in ``obs.scopes``."""
+        Each program's ops are mapped to their scopes in ``obs.scopes``,
+        and ``engine.pool_donated`` counts the pool leaves each program
+        aliases from input to output (all of them when the donation
+        took: 2 a program for a bf16 pool, 4 for int8)."""
         from repro.runtime.meshctx import use_mesh
         t0 = time.monotonic()
         r = self.sched.n_slots
@@ -197,7 +219,11 @@ class Engine:
             with use_mesh(self.mesh):
                 self._steps[c] = self._step_fn(c).lower(*args).compile()
             self.obs.count("engine.builds")
-            self.obs.add_scopes(self._steps[c].as_text(), SCOPE_NAMES)
+            text = self._steps[c].as_text()
+            self.obs.add_scopes(text, SCOPE_NAMES)
+            self.obs.count("engine.pool_donated",
+                           len(pool_aliases(text, len(jax.tree.leaves(
+                               self.paged)))))
         return time.monotonic() - t0
 
     def _run_step(self, tokens: np.ndarray, n_valid: np.ndarray,

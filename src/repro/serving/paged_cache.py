@@ -1,14 +1,18 @@
 """Paged KV cache: fixed-size blocks, per-request block tables, and a
 host-side free-list allocator.
 
-Layout. One global pool per layer holds every request's K/V in
-fixed-size blocks:
+Layout. One stacked pool holds every layer's and every request's K/V
+in fixed-size blocks:
 
     k, v     (L, n_blocks, KV, block_size, dh)      cfg.dtype | int8
     k_scale  (L, n_blocks, KV, block_size) f32      int8 mode only
 
 Head-major blocks: one head's (block_size, dh) chunk is a whole tile of
-the ``flash_decode_paged`` kernel's BlockSpec.
+the ``flash_decode_paged`` kernel's BlockSpec, which addresses it by
+(layer, block). The pool stays one buffer for the whole step: the layer
+scan carries it, each layer writes its token in place and the kernel
+reads it where it lies, and the engine donates it to the step, so the
+pool that comes out reuses the buffer that went in.
 
 A request's cache is the *logical* concatenation of the blocks its
 block-table row names: ``block_tables[r, j]`` is the physical block
@@ -22,7 +26,7 @@ host-side numpy arrays owned by the scheduler and shipped as ordinary
 jit arguments each step, so allocation/eviction never touches device
 state and the step functions stay pure.
 
-Writes go through ``paged_write``: a scatter at ``(block_id, :,
+Writes go through ``paged_write``: a scatter at ``(layer, block_id, :,
 offset)`` with ``mode="drop"`` so inactive rows (idle slots, exhausted
 prefill rows) write nowhere. Reads go
 through the ``flash_decode_paged`` kernel, whose BlockSpec index maps
@@ -41,23 +45,21 @@ Array = jax.Array
 
 
 class PagedKVCache(NamedTuple):
-    """Stacked per-layer block pools (exactly one pool per attention
-    layer; families without KV attention don't page)."""
+    """The block pools of every attention layer, stacked on a leading
+    layer axis (families without KV attention don't page). One buffer,
+    donated to each engine step and updated in place."""
     k: Array                        # (L, n_blocks, KV, bs, dh)
     v: Array                        # (L, n_blocks, KV, bs, dh)
     k_scale: Optional[Array] = None   # (L, n_blocks, KV, bs) f32, int8 only
     v_scale: Optional[Array] = None
 
-    # indexed from the END so the properties are correct both for the
-    # stacked (L, n_blocks, KV, bs, dh) layout and for a single-layer
-    # (n_blocks, KV, bs, dh) slice riding a layer scan
     @property
     def n_blocks(self) -> int:
-        return self.k.shape[-4]
+        return self.k.shape[1]
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[-2]
+        return self.k.shape[3]
 
 
 def init_paged_cache(cfg: ArchConfig, n_blocks: int,
@@ -88,16 +90,27 @@ def paged_cache_axes(cfg: ArchConfig) -> PagedKVCache:
     return PagedKVCache(ax, ax, scale_ax, scale_ax)
 
 
-def paged_write(pool: Array, new: Array, block_ids: Array, offsets: Array,
-                active: Array) -> Array:
-    """Scatter one token per request row into a (single-layer) pool.
+def paged_write(pool: Array, new: Array, layer, block_ids: Array,
+                offsets: Array, active: Array) -> Array:
+    """Scatter one token per request row into layer ``layer`` of the
+    stacked pool, in place when the pool's buffer is free to reuse.
 
-    pool (n_blocks, KV, bs, dh) | (n_blocks, KV, bs); new (R, KV, dh) |
-    (R, KV); block_ids/offsets (R,) int32; active (R,) bool. Inactive
-    rows are routed out of bounds and dropped by the scatter."""
-    blk = jnp.where(active, block_ids, pool.shape[0])
-    return pool.at[blk, :, offsets].set(new.astype(pool.dtype),
-                                        mode="drop")
+    pool (L, n_blocks, KV, bs, dh) | (L, n_blocks, KV, bs); new (R, KV,
+    dh) | (R, KV); layer an int32 scalar; block_ids/offsets (R,) int32;
+    active (R,) bool. Inactive rows are routed out of bounds and dropped
+    by the scatter."""
+    blk = jnp.where(active, block_ids, pool.shape[1])[:, None]
+    heads = jnp.arange(pool.shape[2])[None, :]
+    new = new.astype(pool.dtype)
+    if pool.ndim == 4:
+        # scales: rewrite each (head, block) row of bs scales whole, so
+        # the scatter writes along the minor axis as the K/V one does
+        old = pool[layer, blk, heads]                    # (R, KV, bs)
+        hit = jnp.arange(pool.shape[3]) == offsets[:, None, None]
+        return pool.at[layer, blk, heads].set(
+            jnp.where(hit, new[..., None], old), mode="drop")
+    return pool.at[layer, blk, heads, offsets[:, None]].set(new,
+                                                            mode="drop")
 
 
 class BlockAllocator:

@@ -132,42 +132,65 @@ def _scatter_to_pool(k, v, bs_blk, n_blocks, seed=0):
             jnp.asarray(perm, jnp.int32))
 
 
-def test_flash_decode_paged_matches_ref():
+N_LAYERS = 3
+
+
+def _stacked_pool(per_layer, bs_blk, n_blocks):
+    """Stack each layer's (k, v) pools on a leading layer axis; every
+    layer uses the same block table, as the engine's layers do."""
+    pools = [_scatter_to_pool(k, v, bs_blk, n_blocks) for k, v in per_layer]
+    return (jnp.stack([kp for kp, _, _ in pools]),
+            jnp.stack([vp for _, vp, _ in pools]), pools[0][2])
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_flash_decode_paged_matches_ref(layer):
+    """Each layer of a stacked pool matches the reference on its own
+    K/V; the other layers' blocks hold different values."""
     b, kv, g, dh, s = 3, 2, 2, 32, 60
-    q, k, v, _ = _mk((b, kv, g, dh, s, 16), seed=5)
+    q = _mk((b, kv, g, dh, s, 16), seed=5)[0]
+    kvs = [_mk((b, kv, g, dh, s, 16), seed=5 + i)[1:3]
+           for i in range(N_LAYERS)]
     lengths = jnp.array([60, 13, 1], jnp.int32)
-    kp, vp, bt = _scatter_to_pool(k, v, bs_blk=16, n_blocks=16)
-    want = ref.flash_decode_ref(q, k, v, lengths)
-    got = flash_decode_paged(q, kp, vp, bt, lengths, interpret=True)
+    kp, vp, bt = _stacked_pool(kvs, bs_blk=16, n_blocks=16)
+    want = ref.flash_decode_ref(q, *kvs[layer], lengths)
+    got = flash_decode_paged(q, kp, vp, bt, lengths, layer, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_flash_decode_paged_int8():
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_flash_decode_paged_int8(layer):
     b, kv, g, dh, s = 2, 2, 2, 32, 48
-    q, k, v, _ = _mk((b, kv, g, dh, s, 16), seed=7)
+    q = _mk((b, kv, g, dh, s, 16), seed=7)[0]
+    quant = [(_quant(k), _quant(v)) for k, v in (
+        _mk((b, kv, g, dh, s, 16), seed=7 + i)[1:3]
+        for i in range(N_LAYERS))]
     lengths = jnp.array([48, 29], jnp.int32)
-    kq, ks_ = _quant(k)
-    vq, vs_ = _quant(v)
+    (kq, ks_), (vq, vs_) = quant[layer]
     want = ref.flash_decode_ref(q, kq, vq, lengths, ks_, vs_)
-    kp, vp, bt = _scatter_to_pool(kq, vq, bs_blk=16, n_blocks=8)
-    ksp, vsp, _ = _scatter_to_pool(ks_[..., None], vs_[..., None],
-                                   bs_blk=16, n_blocks=8)
-    got = flash_decode_paged(q, kp, vp, bt, lengths,
+    kp, vp, bt = _stacked_pool([(k[0], v[0]) for k, v in quant],
+                               bs_blk=16, n_blocks=8)
+    ksp, vsp, _ = _stacked_pool([(k[1][..., None], v[1][..., None])
+                                 for k, v in quant], bs_blk=16, n_blocks=8)
+    got = flash_decode_paged(q, kp, vp, bt, lengths, layer,
                              ksp[..., 0], vsp[..., 0], interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_flash_decode_paged_zero_length_rows():
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_flash_decode_paged_zero_length_rows(layer):
     """Inactive slots (length 0) must come back as exact zeros."""
     b, kv, g, dh, s = 2, 2, 2, 16, 32
-    q, k, v, _ = _mk((b, kv, g, dh, s, 16), seed=9)
+    q = _mk((b, kv, g, dh, s, 16), seed=9)[0]
+    kvs = [_mk((b, kv, g, dh, s, 16), seed=9 + i)[1:3]
+           for i in range(N_LAYERS)]
     lengths = jnp.array([32, 0], jnp.int32)
-    kp, vp, bt = _scatter_to_pool(k, v, bs_blk=16, n_blocks=8)
-    got = np.asarray(flash_decode_paged(q, kp, vp, bt, lengths,
+    kp, vp, bt = _stacked_pool(kvs, bs_blk=16, n_blocks=8)
+    got = np.asarray(flash_decode_paged(q, kp, vp, bt, lengths, layer,
                                         interpret=True))
-    want = np.asarray(ref.flash_decode_ref(q, k, v, lengths))
+    want = np.asarray(ref.flash_decode_ref(q, *kvs[layer], lengths))
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
     assert np.array_equal(got[1], np.zeros_like(got[1]))
 

@@ -1,6 +1,8 @@
 """Continuous-batching serving engine: scheduler policy units, paged
 decode-step parity, and end-to-end open-loop traces (dense and
 SLaB-packed) checked token-exact against per-request greedy_decode."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,16 +48,23 @@ def test_blocks_needed():
     assert blocks_needed(17, 16) == 2
 
 
-def test_paged_write_masks_inactive_rows():
-    pool = jnp.zeros((4, 3, 2, 8))       # (n_blocks, KV, bs, dh)
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_write_masks_inactive_rows(layer):
+    pool = jax.random.normal(jax.random.PRNGKey(0),
+                             (3, 4, 3, 2, 8))   # (L, n_blocks, KV, bs, dh)
+    pool = pool.at[layer].set(0.0)
     new = jnp.ones((3, 8))               # one token's (KV, dh) per row
-    out = paged_write(pool, jnp.stack([new, new * 5]),
+    out = paged_write(pool, jnp.stack([new, new * 5]), layer,
                       block_ids=jnp.array([1, 2]),
                       offsets=jnp.array([0, 1]),
                       active=jnp.array([True, False]))
-    assert float(jnp.sum(jnp.abs(out[2]))) == 0.0   # masked row dropped
-    np.testing.assert_allclose(np.asarray(out[1, :, 0]), np.asarray(new))
-    assert float(jnp.sum(jnp.abs(out[1, :, 1]))) == 0.0   # offset 0 only
+    mine = out[layer]
+    assert float(jnp.sum(jnp.abs(mine[2]))) == 0.0   # masked row dropped
+    np.testing.assert_allclose(np.asarray(mine[1, :, 0]), np.asarray(new))
+    assert float(jnp.sum(jnp.abs(mine[1, :, 1]))) == 0.0  # offset 0 only
+    others = [i for i in range(pool.shape[0]) if i != layer]
+    assert np.array_equal(np.asarray(out[others, ...]),
+                          np.asarray(pool[others, ...]))   # bit-identical
 
 
 def test_init_paged_cache_rejects_cacheless_families():
@@ -314,3 +323,38 @@ def test_paged_decode_step_matches_dense_decode(dense_setup):
     rel = (float(jnp.max(jnp.abs(lp[:, 0] - ld[:, -1])))
            / float(jnp.max(jnp.abs(ld))))
     assert rel < 1e-4, rel
+
+
+def test_paged_decode_step_segmentation_is_bit_exact(dense_setup):
+    """The stacked pool rides the layer scan's carry: one scan over all
+    layers and one segment per layer give bit-identical logits and pool
+    over several steps."""
+    cfg, params = dense_setup
+    b, n_blocks, bs = 3, 16, 4
+    rng = np.random.default_rng(7)
+    toks = rng.integers(0, cfg.vocab, size=(b, 6)).astype(np.int32)
+    bt = jnp.asarray(rng.permutation(n_blocks)[:b * 2].reshape(b, 2),
+                     jnp.int32)
+    active = jnp.asarray([True, True, False])
+    per_layer = tuple((i, i + 1) for i in range(cfg.n_layers))
+    assert cfg.n_layers > 1
+    pools = {seg: init_paged_cache(cfg, n_blocks, bs)
+             for seg in (None, per_layer)}
+    steps = {seg: jax.jit(functools.partial(lm.paged_decode_step, cfg,
+                                            segments=seg))
+             for seg in pools}
+    for t in range(6):
+        lengths = jnp.full((b,), t, jnp.int32)
+        tok = jnp.asarray(toks[:, t:t + 1])
+        logits = {}
+        for seg in pools:
+            logits[seg], pools[seg] = steps[seg](
+                params, pools[seg], bt, lengths, tok, active)
+        assert np.array_equal(np.asarray(logits[None]),
+                              np.asarray(logits[per_layer]))
+    for a, c in zip(pools[None], pools[per_layer]):
+        assert np.array_equal(np.asarray(a), np.asarray(c))
+    # the inactive row wrote nothing, the live rows wrote every layer
+    k = np.asarray(pools[None].k)
+    assert not np.any(k[:, np.asarray(bt[2])])
+    assert np.all(np.any(k[:, np.asarray(bt[0, 0])], axis=(1, 2, 3)))

@@ -1,13 +1,15 @@
-"""The main-path Pallas kernels compile for a TPU v5e chip.
+"""The main-path Pallas kernels and the engine's step compile for a TPU
+v5e chip.
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler
 for a *described* v5e chip (no chip attached) at StableLM-2-12B widths
 — d_model 5120, d_ff 13824, 8 KV heads x 160, 16-token KV blocks, the
 engine's 8 rows per matmul. This is what interpret-mode tests cannot
 see: block shapes the Mosaic tiling refuses, layouts it cannot lower,
-kernels over the VMEM limit. The topology is described inside a module
-fixture, so only the worker that runs this file loads the TPU
-compiler; everything built from it lives in fixtures or tests too.
+kernels over the VMEM limit, and copies the compiler adds around them.
+The topology is described inside a module fixture, so only the worker
+that runs this file loads the TPU compiler; everything built from it
+lives in fixtures or tests too.
 """
 import os
 import re
@@ -17,14 +19,21 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import numpy as np
+
+from repro import configs
 from repro.core.packed_model import PackedLinear, _kernel_blocks
 from repro.kernels import ops
 from repro.kernels.ell import ell_matmul
 from repro.kernels.flash_decode import flash_decode_paged
+from repro.models import lm
+from repro.serving import Engine, EngineConfig
+from repro.serving.engine import pool_aliases
 
 D_MODEL, D_FF = 5120, 13824
 N_KV, GROUP, D_HEAD, BLOCK = 8, 4, 160, 16
 ROWS, N_BLOCKS, TABLE = 8, 144, 18        # 8 slots x 18 blocks of 16
+N_LAYERS = 5
 
 
 @pytest.fixture(scope="module")
@@ -60,11 +69,13 @@ def _compile(fn, sharding, *shapes):
 
 @pytest.mark.parametrize("quant", [False, True], ids=("bf16", "int8"))
 def test_flash_decode_paged_compiles(one_chip, quant):
+    """The kernel reads one layer of the stacked pool in place."""
     kv_dt = jnp.int8 if quant else jnp.bfloat16
-    pool = (N_BLOCKS, N_KV, BLOCK, D_HEAD)
+    pool = (N_LAYERS, N_BLOCKS, N_KV, BLOCK, D_HEAD)
     shapes = [((ROWS, N_KV, GROUP, D_HEAD), jnp.bfloat16),
               (pool, kv_dt), (pool, kv_dt),
-              ((ROWS, TABLE), jnp.int32), ((ROWS,), jnp.int32)]
+              ((ROWS, TABLE), jnp.int32), ((ROWS,), jnp.int32),
+              ((), jnp.int32)]
     if quant:
         shapes += [(pool[:-1], jnp.float32)] * 2
     compiled = _compile(lambda *a: flash_decode_paged(*a, interpret=False),
@@ -72,6 +83,39 @@ def test_flash_decode_paged_compiles(one_chip, quant):
     # the device trace names the kernel's op after the pallas_call
     assert re.search(r"^\s*(ROOT )?%flash_decode_paged(\.\d+)? = ",
                      compiled.as_text(), re.M)
+    # no layer's pool is sliced out of the stacked one to be read
+    assert not re.search(r"= \S+\[%d,%d,%d,%d\]" % pool[1:],
+                         compiled.as_text())
+
+
+def test_engine_step_updates_the_pool_in_place(one_chip, monkeypatch):
+    """The engine's C = 8 step at tiny widths (2 layers, 64 blocks, the
+    real 128-wide heads): the pool is donated, its buffers come back as
+    outputs, and no copy, slice or update-slice in the program has the
+    shape of one layer's pool or of the stacked one."""
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)   # Mosaic kernels
+    cfg = configs.get("mistral_nemo_12b", smoke=True).with_(
+        n_layers=2, d_head=128)
+    params = jax.eval_shape(lambda k: lm.init(cfg, k)[0],
+                            jax.random.PRNGKey(0))
+    r, c = 4, 8
+    eng = Engine(cfg, params, EngineConfig(n_slots=r, n_blocks=64,
+                                           block_size=BLOCK, max_len=128,
+                                           prefill_chunk=c))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._step_args(np.zeros((r, c), np.int32), np.zeros((r,), np.int32),
+                       np.zeros((r,), bool)))
+    text = eng._step_fn(c).lower(*args).compile().as_text()
+    n_pool = len(jax.tree.leaves(eng.paged))
+    assert pool_aliases(text, n_pool) == list(range(n_pool))
+    layer = eng.paged.k.shape[1:]
+    for shape in (layer, eng.paged.k.shape):
+        dims = ",".join(map(str, shape))
+        moved = re.findall(
+            r"= \w+\[%s\]\S* (?:copy|dynamic-slice|dynamic-update-slice)\("
+            % dims, text)
+        assert not moved, (shape, moved)
 
 
 @pytest.mark.parametrize("d_in,d_out", [(D_MODEL, D_FF), (D_FF, D_MODEL)],
