@@ -1,4 +1,5 @@
-"""Smoke run of the serving engine on a TPU at StableLM-2-12B widths.
+"""Smoke run of the serving engine on a TPU with StableLM-2-12B's block
+at its published widths.
 
     python chip_smoke.py               # one chip: phases (a) and (b)
     python chip_smoke.py --four-chips  # phase (b) on a (1, 4) mesh vs the
@@ -6,8 +7,10 @@
 
 It drives the code ``python -m repro.launch.serve --engine --packed``
 drives (``serve.serve``): random weights from ``--seed``, the
-``stablelm_12b`` config at every published width with its depth cut to
-4 layers (about 2.1 B parameters, 4.3 GB in bf16), and one seeded trace
+``stablelm_12b`` config (StableLM-2-12B: LayerNorm, per-head q/k norm,
+rotary on 40 of 160 dims, parallel residual) at every published width
+with its depth cut to 4 layers (about 2.1 B parameters, 4.3 GB in
+bf16), and one seeded trace
 of 8 requests (prompts of 64-256 tokens, 16-32 new tokens) through 8
 engine slots over 16-token KV blocks.
 

@@ -347,8 +347,10 @@ def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
                                  interpret: Optional[bool] = None) -> Array:
     """Paged (block-table) grouped-query decode attention over one layer
     of the stacked pool. q (R, KV, G, dh) pre-scaled; k_pool/v_pool
-    (L, n_blocks, KV, bs, dh); block_tables (R, n_bt); lengths (R,) —
-    zero-length rows return 0; layer an int32 scalar.
+    (L, n_blocks, KV, bs, W), W >= dh with zeros past dh (the pool's
+    lane padding: q is padded to W and the output cut back to dh);
+    block_tables (R, n_bt); lengths (R,) — zero-length rows return 0;
+    layer an int32 scalar.
 
     Under a multi-device mesh the kernel runs inside ``shard_map`` (the
     chip's compiler cannot partition a Pallas kernel): each device
@@ -358,20 +360,26 @@ def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
     from repro.runtime.meshctx import current_mesh
     interpret = _on_cpu() if interpret is None else interpret
     fn = functools.partial(flash_decode_paged, interpret=interpret)
+    dh, pad = q.shape[-1], k_pool.shape[-1] - q.shape[-1]
+    if pad:
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
     args = (q, k_pool, v_pool, block_tables, lengths, layer, k_scale,
             v_scale)
     mesh = current_mesh()
     if mesh is None or mesh.size == 1:
-        return fn(*args)
-    from jax.sharding import PartitionSpec as P
-    n_model = dict(mesh.shape).get("model", 1)
-    ax = "model" if n_model > 1 and q.shape[1] % n_model == 0 else None
-    heads, pool = P(None, ax), P(None, None, ax)
-    scale = pool if k_scale is not None else None
-    return jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(heads, pool, pool, P(), P(), P(), scale, scale),
-        out_specs=heads, check_vma=False)(*args)
+        out = fn(*args)
+    else:
+        from jax.sharding import PartitionSpec as P
+        n_model = dict(mesh.shape).get("model", 1)
+        ax = ("model" if n_model > 1 and q.shape[1] % n_model == 0
+              else None)
+        heads, pool = P(None, ax), P(None, None, ax)
+        scale = pool if k_scale is not None else None
+        out = jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(heads, pool, pool, P(), P(), P(), scale, scale),
+            out_specs=heads, check_vma=False)(*args)
+    return out[..., :dh] if pad else out
 
 
 def slab_linear_kernel(x: Array, packed: SLaBPacked, **kw) -> Array:

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packed_model import linear
-from repro.models.common import ArchConfig, dense_init, rotate, scope
+from repro.models.common import (ArchConfig, dense_init, layer_norm,
+                                 rotate, scope)
 from repro.runtime.meshctx import hint
 
 Array = jax.Array
@@ -32,13 +33,17 @@ Array = jax.Array
 NEG_INF = -1e30
 
 
-def attention_axes() -> dict:
-    return {
+def attention_axes(cfg: ArchConfig) -> dict:
+    a = {
         "wq": ("embed", "heads"),
         "wk": ("embed", "kv"),
         "wv": ("embed", "kv"),
         "wo": ("heads", "embed"),
     }
+    if cfg.qk_norm:
+        a["q_norm"] = (None, None)
+        a["k_norm"] = (None, None)
+    return a
 
 
 def init_attention(cfg: ArchConfig, key: Array):
@@ -50,7 +55,31 @@ def init_attention(cfg: ArchConfig, key: Array):
         "wv": dense_init(ks[2], (d, cfg.d_kv), d, cfg.dtype),
         "wo": dense_init(ks[3], (cfg.d_q, d), cfg.d_q, cfg.dtype),
     }
-    return p, attention_axes()
+    if cfg.qk_norm:
+        # one LayerNorm scale per head: (H, dh) and (KV, dh)
+        p["q_norm"] = jnp.ones((cfg.n_heads, cfg.d_head), jnp.float32)
+        p["k_norm"] = jnp.ones((cfg.n_kv, cfg.d_head), jnp.float32)
+    return p, attention_axes(cfg)
+
+
+def project_qkv(cfg: ArchConfig, p: dict, x: Array, positions: Array
+                ) -> Tuple[Array, Array, Array]:
+    """x (B, S, D) -> q (B, S, H, dh), k and v (B, S, KV, dh): the three
+    projections, then (``cfg.qk_norm``) a LayerNorm of each q and k head
+    with that head's own scale, then rotary on q and k."""
+    b, s, _ = x.shape
+    dh = cfg.d_head
+    q = linear(x, p["wq"], tap="wq").reshape(b, s, cfg.n_heads, dh)
+    k = linear(x, p["wk"], tap="wk").reshape(b, s, cfg.n_kv, dh)
+    v = linear(x, p["wv"], tap="wv").reshape(b, s, cfg.n_kv, dh)
+    if cfg.qk_norm:
+        with scope("qk_norm"):
+            q = layer_norm(q, p["q_norm"], eps=cfg.norm_eps)
+            k = layer_norm(k, p["k_norm"], eps=cfg.norm_eps)
+    with scope("rope"):
+        q = rotate(cfg, q, positions)
+        k = rotate(cfg, k, positions)
+    return q, k, v
 
 
 def multihead_attention(
@@ -65,11 +94,7 @@ def multihead_attention(
     b, s, d = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv, cfg.d_head
     g = h // kv
-    q = linear(x, p["wq"], tap="wq").reshape(b, s, h, dh)
-    k = linear(x, p["wk"], tap="wk").reshape(b, s, kv, dh)
-    v = linear(x, p["wv"], tap="wv").reshape(b, s, kv, dh)
-    q = rotate(cfg, q, positions)
-    k = rotate(cfg, k, positions)
+    q, k, v = project_qkv(cfg, p, x, positions)
     if g > 1:                       # expand KV to full heads: clean TP on H
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
@@ -176,11 +201,7 @@ def decode_attention(
     convert fuses into the dot's operand pipeline)."""
     b, s, d = x.shape
     kv, g, dh = cfg.n_kv, cfg.n_heads // cfg.n_kv, cfg.d_head
-    q = linear(x, p["wq"], tap="wq").reshape(b, s, cfg.n_heads, dh)
-    k_new = linear(x, p["wk"], tap="wk").reshape(b, s, kv, dh)
-    v_new = linear(x, p["wv"], tap="wv").reshape(b, s, kv, dh)
-    q = rotate(cfg, q, positions)
-    k_new = rotate(cfg, k_new, positions)
+    q, k_new, v_new = project_qkv(cfg, p, x, positions)
 
     idx = cache.length
     if cfg.kv_quant:
@@ -227,7 +248,8 @@ def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
     """One-token decode of layer ``layer`` against the paged KV cache.
 
     x (R, 1, D); pool the stacked ``serving.paged_cache.PagedKVCache``
-    (k/v (L, n_blocks, KV, bs, dh)); layer an int32 scalar;
+    (k/v (L, n_blocks, KV, bs, W), W the head dim rounded up to whole
+    lanes); layer an int32 scalar;
     block_tables (R, n_bt) int32; lengths (R,) tokens already cached per
     row (also the write position); active (R,) bool — inactive rows
     write nothing and return zeros. Returns (out (R, 1, D), updated
@@ -241,14 +263,11 @@ def paged_decode_attention(cfg: ArchConfig, p: dict, x: Array, pool,
     from repro.serving.paged_cache import paged_write
     b, s, d = x.shape
     kv, g, dh = cfg.n_kv, cfg.n_heads // cfg.n_kv, cfg.d_head
-    q = linear(x, p["wq"], tap="wq").reshape(b, s, cfg.n_heads, dh)
-    k_new = linear(x, p["wk"], tap="wk").reshape(b, s, kv, dh)
-    v_new = linear(x, p["wv"], tap="wv").reshape(b, s, kv, dh)
-    q = rotate(cfg, q, positions)
-    k_new = rotate(cfg, k_new, positions)
+    q, k_new, v_new = project_qkv(cfg, p, x, positions)
 
     bs_blk = pool.block_size
     n_bt = block_tables.shape[1]
+
     with scope("kv_write"):
         # physical write slot; clamp shields idle rows with stale
         # lengths (their write is dropped by `active` anyway)

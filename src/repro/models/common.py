@@ -294,6 +294,15 @@ class ArchConfig:
     input_mode: str = "tokens"   # tokens | embeds (audio/vlm stub frontends)
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
+    # the block: "rms" norms, or "layer" (LayerNorm with a bias); the
+    # share of each head's leading dims that rotary rotates; a per-head
+    # LayerNorm (scale only) on q and k before rotary; attention and MLP
+    # reading one normalized input beside the residual (one norm per
+    # layer) instead of one after the other
+    norm: str = "rms"
+    rotary_pct: float = 1.0
+    qk_norm: bool = False
+    parallel_residual: bool = False
     q_chunk: int = 512           # query-chunked attention block size
     kv_quant: bool = False       # int8 KV cache (beyond-paper serve opt)
     dtype: Any = jnp.bfloat16
@@ -305,6 +314,10 @@ class ArchConfig:
     @property
     def d_kv(self) -> int:
         return self.n_kv * self.d_head
+
+    @property
+    def rotary_dims(self) -> int:
+        return int(self.d_head * self.rotary_pct)
 
     @property
     def d_inner(self) -> int:
@@ -347,6 +360,21 @@ def rms_norm(x: Array, scale: Array, eps: float = 1e-5) -> Array:
     x = x.astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(dt)
+
+
+def layer_norm(x: Array, scale: Array, bias: Optional[Array] = None,
+               eps: float = 1e-5) -> Array:
+    """LayerNorm over the last axis in float32; ``scale`` (and ``bias``)
+    broadcast against x's trailing axes, so an (H, dh) scale normalizes
+    each head of an (..., H, dh) input with its own weight."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    xc = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    y = xc * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(dt)
 
 
 def activation(x: Array, kind: str) -> Array:
@@ -409,12 +437,23 @@ def positions_for(cfg: ArchConfig, batch: int, seq: int,
     return pos
 
 
-def rotate(cfg: ArchConfig, x: Array, positions: Array) -> Array:
+def _rotate_all(cfg: ArchConfig, x: Array, positions: Array) -> Array:
     if cfg.rope == "rope":
         return apply_rope(x, positions, cfg.rope_theta)
     if cfg.rope == "mrope":
         return apply_mrope(x, positions, cfg.rope_theta, cfg.mrope_sections)
     return x
+
+
+def rotate(cfg: ArchConfig, x: Array, positions: Array) -> Array:
+    """Rotary on the leading ``cfg.rotary_dims`` of each head, with the
+    frequencies and split-half pairs of that slice; the other dims pass
+    unchanged."""
+    n = cfg.rotary_dims
+    if n < x.shape[-1]:
+        return jnp.concatenate(
+            [_rotate_all(cfg, x[..., :n], positions), x[..., n:]], axis=-1)
+    return _rotate_all(cfg, x, positions)
 
 
 # ------------------------------------------------------------------

@@ -33,11 +33,42 @@ from repro.models import mamba2 as mamba_lib
 from repro.models import mlp as mlp_lib
 from repro.models import moe as moe_lib
 from repro.models.common import (ArchConfig, embed_init, dense_init,
-                                 is_axes_leaf, positions_for, rms_norm,
-                                 scope, softmax_xent, tap_scope)
+                                 is_axes_leaf, layer_norm, positions_for,
+                                 rms_norm, scope, softmax_xent, tap_scope)
 
 Array = jax.Array
 AUX_LOSS_WEIGHT = 0.01
+
+
+# ------------------------------------------------------------------
+# Norms
+# ------------------------------------------------------------------
+
+def _norm_names(cfg: ArchConfig, name: str) -> Tuple[str, ...]:
+    """The leaves of norm ``name``: its scale, and a bias ("<name>_bias")
+    for LayerNorm."""
+    return (name, name + "_bias") if cfg.norm == "layer" else (name,)
+
+
+def _block_norms(cfg: ArchConfig) -> Tuple[str, ...]:
+    """Norms of one attention layer: one before attention, and one
+    before the MLP unless the two read the same input."""
+    names = ("attn_norm",) if cfg.parallel_residual else ("attn_norm",
+                                                           "mlp_norm")
+    return tuple(n for name in names for n in _norm_names(cfg, name))
+
+
+def _init_norms(cfg: ArchConfig, names: Tuple[str, ...]) -> dict:
+    return {n: (jnp.zeros if n.endswith("_bias") else jnp.ones)(
+        (cfg.d_model,), jnp.float32) for n in names}
+
+
+def _norm(cfg: ArchConfig, p: dict, name: str, x: Array) -> Array:
+    """Norm ``name`` of ``p`` applied to x: RMSNorm, or LayerNorm with
+    its bias."""
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[name + "_bias"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
 
 
 # ------------------------------------------------------------------
@@ -51,9 +82,8 @@ def _init_layer(cfg: ArchConfig, key: Array):
         mp, ma = mamba_lib.init_mamba(cfg, ks[0])
         return ({"norm": jnp.ones((cfg.d_model,), jnp.float32), "mamba": mp},
                 {"norm": ("embed",), "mamba": ma})
-    p: dict = {"attn_norm": jnp.ones((cfg.d_model,), jnp.float32),
-               "mlp_norm": jnp.ones((cfg.d_model,), jnp.float32)}
-    a: dict = {"attn_norm": ("embed",), "mlp_norm": ("embed",)}
+    p: dict = _init_norms(cfg, _block_norms(cfg))
+    a: dict = {n: ("embed",) for n in p}
     p["attn"], a["attn"] = attn_lib.init_attention(cfg, ks[1])
     if cfg.family == "moe":
         p["moe"], a["moe"] = moe_lib.init_moe(cfg, ks[2])
@@ -69,15 +99,14 @@ def init(cfg: ArchConfig, key: Array):
     layers = jax.vmap(lambda k: _init_layer(cfg, k)[0])(layer_keys)
 
     params: dict = {"layers": layers,
-                    "final_norm": jnp.ones((cfg.d_model,), jnp.float32)}
+                    **_init_norms(cfg, _norm_names(cfg, "final_norm"))}
     if cfg.input_mode == "tokens" or cfg.family == "vlm":
         params["embed"] = embed_init(ke, (cfg.vocab, cfg.d_model), cfg.dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(kh, (cfg.d_model, cfg.vocab),
                                        cfg.d_model, cfg.dtype)
     if cfg.family == "hybrid":
-        sp: dict = {"attn_norm": jnp.ones((cfg.d_model,), jnp.float32),
-                    "mlp_norm": jnp.ones((cfg.d_model,), jnp.float32)}
+        sp: dict = _init_norms(cfg, _block_norms(cfg))
         k1, k2 = jax.random.split(ks)
         sp["attn"], _ = attn_lib.init_attention(cfg, k1)
         sp["mlp"], _ = mlp_lib.init_mlp(cfg, k2)
@@ -95,8 +124,8 @@ def _layer_axes(cfg: ArchConfig) -> dict:
     """Static logical axes of one layer — no array allocation."""
     if cfg.family in ("ssm", "hybrid"):
         return {"norm": ("embed",), "mamba": mamba_lib.mamba_axes()}
-    a: dict = {"attn_norm": ("embed",), "mlp_norm": ("embed",),
-               "attn": attn_lib.attention_axes()}
+    a: dict = {n: ("embed",) for n in _block_norms(cfg)}
+    a["attn"] = attn_lib.attention_axes(cfg)
     if cfg.family == "moe":
         a["moe"] = moe_lib.moe_axes(cfg)
     else:
@@ -109,15 +138,17 @@ def param_axes(cfg: ArchConfig):
     layer_axes = jax.tree.map(lambda ax: ("layers",) + tuple(ax),
                               _layer_axes(cfg),
                               is_leaf=is_axes_leaf)
-    axes: dict = {"layers": layer_axes, "final_norm": ("embed",)}
+    axes: dict = {"layers": layer_axes,
+                  **{n: ("embed",) for n in _norm_names(cfg, "final_norm")}}
     if cfg.input_mode == "tokens" or cfg.family == "vlm":
         axes["embed"] = ("vocab", "embed")
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     if cfg.family == "hybrid":
         axes["shared_attn"] = {
-            "attn_norm": ("embed",), "mlp_norm": ("embed",),
-            "attn": attn_lib.attention_axes(), "mlp": mlp_lib.mlp_axes(cfg)}
+            **{n: ("embed",) for n in _block_norms(cfg)},
+            "attn": attn_lib.attention_axes(cfg),
+            "mlp": mlp_lib.mlp_axes(cfg)}
     return axes
 
 
@@ -143,18 +174,37 @@ def active_param_count(cfg: ArchConfig) -> int:
 # Forward (train / prefill)
 # ------------------------------------------------------------------
 
+def _block(cfg: ArchConfig, lp: dict, h: Array, attend):
+    """One attention layer on the residual stream h: the attention norm,
+    ``attend(x)`` -> (out, cache) on the normed x, then the MLP (or
+    MoE) on the stream after attention through its own norm, or
+    (``cfg.parallel_residual``) on the same x, added beside attention.
+    Returns (h, MoE aux loss or None, cache)."""
+    with tap_scope("attn"):
+        x = _norm(cfg, lp, "attn_norm", h)
+        a, cache = attend(x)
+    if cfg.parallel_residual:
+        hin = x
+    else:
+        h = h + a
+        hin = _norm(cfg, lp, "mlp_norm", h)
+    aux = None
+    if cfg.family == "moe":
+        with tap_scope("moe"):
+            y, aux = moe_lib.moe_ffn(cfg, lp["moe"], hin)
+    else:
+        with tap_scope("mlp"):
+            y = mlp_lib.mlp(cfg, lp["mlp"], hin)
+    if cfg.parallel_residual:
+        return h + a + y, aux, cache
+    return h + y, aux, cache
+
+
 def _shared_block(cfg: ArchConfig, sp: dict, h: Array, positions: Array
                   ) -> Array:
     with tap_scope("shared"):
-        with tap_scope("attn"):
-            a = attn_lib.multihead_attention(
-                cfg, sp["attn"], rms_norm(h, sp["attn_norm"], cfg.norm_eps),
-                positions)
-        h = h + a
-        with tap_scope("mlp"):
-            m = mlp_lib.mlp(cfg, sp["mlp"],
-                            rms_norm(h, sp["mlp_norm"], cfg.norm_eps))
-    return h + m
+        return _block(cfg, sp, h, lambda x: (attn_lib.multihead_attention(
+            cfg, sp["attn"], x, positions), None))[0]
 
 
 def _layer_fwd(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
@@ -178,19 +228,9 @@ def _layer_fwd(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
             h = h + mamba_lib.mamba_block(
                 cfg, lp["mamba"], rms_norm(h, lp["norm"], cfg.norm_eps))
         return h, aux
-    with tap_scope("attn"):
-        a = attn_lib.multihead_attention(
-            cfg, lp["attn"], rms_norm(h, lp["attn_norm"], cfg.norm_eps),
-            positions)
-    h = h + a
-    hin = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.family == "moe":
-        with tap_scope("moe"):
-            y, aux = moe_lib.moe_ffn(cfg, lp["moe"], hin)
-    else:
-        with tap_scope("mlp"):
-            y = mlp_lib.mlp(cfg, lp["mlp"], hin)
-    return h + y, aux
+    h, moe_aux, _ = _block(cfg, lp, h, lambda x: (
+        attn_lib.multihead_attention(cfg, lp["attn"], x, positions), None))
+    return h, aux if moe_aux is None else moe_aux
 
 
 def embed_inputs(cfg: ArchConfig, params: dict, inputs: Array) -> Array:
@@ -256,7 +296,7 @@ def forward(cfg: ArchConfig, params: dict, inputs: Array,
                 (layer_slice_range(stacked, lo, hi), jnp.arange(lo, hi)),
                 hi - lo)
         h, aux = carry
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        h = _norm(cfg, params, "final_norm", h)
         return unembed(cfg, params, h), aux
 
     init = (h, jnp.zeros((), jnp.float32))
@@ -278,7 +318,7 @@ def forward(cfg: ArchConfig, params: dict, inputs: Array,
             body = jax.checkpoint(body, policy=remat_policy)
         (h, aux), _ = jax.lax.scan(
             body, init, (stacked, jnp.arange(cfg.n_layers)))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    h = _norm(cfg, params, "final_norm", h)
     return unembed(cfg, params, h), aux
 
 
@@ -351,33 +391,17 @@ def _layer_decode(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
             y, mc = mamba_lib.mamba_decode_step(
                 cfg, lp["mamba"], rms_norm(h, lp["norm"], cfg.norm_eps), kv_l)
         return h + y, mc
-    with tap_scope("attn"):
-        a, kc = attn_lib.decode_attention(
-            cfg, lp["attn"], rms_norm(h, lp["attn_norm"], cfg.norm_eps),
-            kv_l, positions)
-    h = h + a
-    hin = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.family == "moe":
-        with tap_scope("moe"):
-            y, _ = moe_lib.moe_ffn(cfg, lp["moe"], hin)
-    else:
-        with tap_scope("mlp"):
-            y = mlp_lib.mlp(cfg, lp["mlp"], hin)
-    return h + y, kc
+    h, _, kc = _block(cfg, lp, h, lambda x: attn_lib.decode_attention(
+        cfg, lp["attn"], x, kv_l, positions))
+    return h, kc
 
 
 def _shared_block_decode(cfg: ArchConfig, sp: dict, h: Array,
                          kv: attn_lib.KVCache, positions: Array):
     with tap_scope("shared"):
-        with tap_scope("attn"):
-            a, kv = attn_lib.decode_attention(
-                cfg, sp["attn"], rms_norm(h, sp["attn_norm"], cfg.norm_eps),
-                kv, positions)
-        h = h + a
-        with tap_scope("mlp"):
-            m = mlp_lib.mlp(cfg, sp["mlp"],
-                            rms_norm(h, sp["mlp_norm"], cfg.norm_eps))
-    return h + m, kv
+        h, _, kv = _block(cfg, sp, h, lambda x: attn_lib.decode_attention(
+            cfg, sp["attn"], x, kv, positions))
+    return h, kv
 
 
 def _cat_parts(parts):
@@ -504,26 +528,18 @@ def decode_step(cfg: ArchConfig, params: dict, cache: LayerCache,
             kv_parts.append(kv_new)
         new_cache = LayerCache(_cat_parts(kv_parts), None, None)
 
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    h = _norm(cfg, params, "final_norm", h)
     return unembed(cfg, params, h), new_cache
 
 
 def _layer_decode_paged(cfg: ArchConfig, params: dict, lp: dict, idx: Array,
                         h: Array, pool, block_tables: Array,
                         lengths: Array, positions: Array, active: Array):
-    with tap_scope("attn"):
-        a, pool = attn_lib.paged_decode_attention(
-            cfg, lp["attn"], rms_norm(h, lp["attn_norm"], cfg.norm_eps),
-            pool, idx, block_tables, lengths, positions, active)
-    h = h + a
-    hin = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.family == "moe":
-        with tap_scope("moe"):
-            y, _ = moe_lib.moe_ffn(cfg, lp["moe"], hin)
-    else:
-        with tap_scope("mlp"):
-            y = mlp_lib.mlp(cfg, lp["mlp"], hin)
-    return h + y, pool
+    h, _, pool = _block(
+        cfg, lp, h, lambda x: attn_lib.paged_decode_attention(
+            cfg, lp["attn"], x, pool, idx, block_tables, lengths, positions,
+            active))
+    return h, pool
 
 
 def paged_decode_step(cfg: ArchConfig, params: dict, paged,
@@ -577,7 +593,7 @@ def paged_decode_step(cfg: ArchConfig, params: dict, paged,
     h, paged = carry
 
     with scope("head"):
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        h = _norm(cfg, params, "final_norm", h)
         return unembed(cfg, params, h), paged
 
 

@@ -46,7 +46,8 @@ Observability: ``engine.obs`` (``serving/obs.py``) records one
 request's arrival / admission / first token / finish, the scheduler's
 work counters and the step programs built; the device ops of each
 program carry named scopes (``embed``, ``layer_scan``, ``attn/wq``,
-``kv_write``, ``paged_attn``, ``head``, ``finite_check``, ``sample``).
+``attn/qk_norm``, ``attn/rope``, ``kv_write``, ``paged_attn``, ``head``,
+``finite_check``, ``sample``).
 """
 from __future__ import annotations
 

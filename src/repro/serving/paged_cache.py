@@ -4,13 +4,20 @@ host-side free-list allocator.
 Layout. One stacked pool holds every layer's and every request's K/V
 in fixed-size blocks:
 
-    k, v     (L, n_blocks, KV, block_size, dh)      cfg.dtype | int8
+    k, v     (L, n_blocks, KV, block_size, W)       cfg.dtype | int8
     k_scale  (L, n_blocks, KV, block_size) f32      int8 mode only
 
-Head-major blocks: one head's (block_size, dh) chunk is a whole tile of
+Head-major blocks: one head's (block_size, W) chunk is a whole tile of
 the ``flash_decode_paged`` kernel's BlockSpec, which addresses it by
-(layer, block). The pool stays one buffer for the whole step: the layer
-scan carries it, each layer writes its token in place and the kernel
+(layer, block). W is the head dim rounded up to the TPU's 128 lanes
+(``pool_width``): the kernel's tiles are lane-padded in any case, and
+a pool whose last dim is not a multiple of 128 is given a different
+memory layout by the TPU compiler (blocks minor, to save the padding),
+which it then copies to the kernel's layout and back at every step.
+The lanes past the head hold zeros (``paged_write`` pads each token;
+``ops.flash_decode_paged_attention`` pads q and drops them from its
+output). The pool stays one buffer for the whole step: the layer scan
+carries it, each layer writes its token in place and the kernel
 reads it where it lies, and the engine donates it to the step, so the
 pool that comes out reuses the buffer that went in.
 
@@ -48,8 +55,8 @@ class PagedKVCache(NamedTuple):
     """The block pools of every attention layer, stacked on a leading
     layer axis (families without KV attention don't page). One buffer,
     donated to each engine step and updated in place."""
-    k: Array                        # (L, n_blocks, KV, bs, dh)
-    v: Array                        # (L, n_blocks, KV, bs, dh)
+    k: Array                        # (L, n_blocks, KV, bs, W)
+    v: Array                        # (L, n_blocks, KV, bs, W)
     k_scale: Optional[Array] = None   # (L, n_blocks, KV, bs) f32, int8 only
     v_scale: Optional[Array] = None
 
@@ -62,13 +69,22 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[3]
 
 
+LANES = 128
+
+
+def pool_width(d_head: int) -> int:
+    """The pool's last dim: the head dim rounded up to whole lanes."""
+    return -(-d_head // LANES) * LANES
+
+
 def init_paged_cache(cfg: ArchConfig, n_blocks: int,
                      block_size: int) -> PagedKVCache:
     if cfg.family in ("ssm", "hybrid", "audio"):
         raise ValueError(
             f"paged KV serving needs a KV-attention family, not "
             f"{cfg.family!r} (SSM state is O(1) — it doesn't page)")
-    shp = (cfg.n_layers, n_blocks, cfg.n_kv, block_size, cfg.d_head)
+    shp = (cfg.n_layers, n_blocks, cfg.n_kv, block_size,
+           pool_width(cfg.d_head))
     if cfg.kv_quant:
         sshp = shp[:-1]
         return PagedKVCache(jnp.zeros(shp, jnp.int8),
@@ -95,10 +111,10 @@ def paged_write(pool: Array, new: Array, layer, block_ids: Array,
     """Scatter one token per request row into layer ``layer`` of the
     stacked pool, in place when the pool's buffer is free to reuse.
 
-    pool (L, n_blocks, KV, bs, dh) | (L, n_blocks, KV, bs); new (R, KV,
-    dh) | (R, KV); layer an int32 scalar; block_ids/offsets (R,) int32;
-    active (R,) bool. Inactive rows are routed out of bounds and dropped
-    by the scatter."""
+    pool (L, n_blocks, KV, bs, W) | (L, n_blocks, KV, bs); new (R, KV,
+    dh) | (R, KV), padded with zeros to W; layer an int32 scalar;
+    block_ids/offsets (R,) int32; active (R,) bool. Inactive rows are
+    routed out of bounds and dropped by the scatter."""
     blk = jnp.where(active, block_ids, pool.shape[1])[:, None]
     heads = jnp.arange(pool.shape[2])[None, :]
     new = new.astype(pool.dtype)
@@ -109,6 +125,9 @@ def paged_write(pool: Array, new: Array, layer, block_ids: Array,
         hit = jnp.arange(pool.shape[3]) == offsets[:, None, None]
         return pool.at[layer, blk, heads].set(
             jnp.where(hit, new[..., None], old), mode="drop")
+    pad = pool.shape[-1] - new.shape[-1]
+    if pad:
+        new = jnp.pad(new, ((0, 0), (0, 0), (0, pad)))
     return pool.at[layer, blk, heads, offsets[:, None]].set(new,
                                                             mode="drop")
 

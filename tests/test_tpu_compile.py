@@ -88,18 +88,24 @@ def test_flash_decode_paged_compiles(one_chip, quant):
                          compiled.as_text())
 
 
-def test_engine_step_updates_the_pool_in_place(one_chip, monkeypatch):
-    """The engine's C = 8 step at tiny widths (2 layers, 64 blocks, the
-    real 128-wide heads): the pool is donated, its buffers come back as
-    outputs, and no copy, slice or update-slice in the program has the
-    shape of one layer's pool or of the stacked one."""
+@pytest.mark.parametrize("arch,d_head", [("mistral_nemo_12b", 128),
+                                          ("stablelm_12b", D_HEAD)],
+                         ids=("mistral", "stablelm"))
+def test_engine_step_updates_the_pool_in_place(one_chip, monkeypatch, arch,
+                                               d_head):
+    """The engine's C = 8 step at tiny widths (2 layers, each model's
+    real head width: Mistral-NeMo's 128, and StableLM-2's 160 with its
+    own block): the pool is donated, its buffers come back as outputs,
+    and no copy, slice or update-slice in the program has the shape of
+    one layer's pool or of the stacked one. 128 blocks, so that a pool
+    whose last dim were not whole lanes would get a blocks-minor layout
+    from the compiler, and copies to the kernel's layout and back."""
     monkeypatch.setattr(ops, "_on_cpu", lambda: False)   # Mosaic kernels
-    cfg = configs.get("mistral_nemo_12b", smoke=True).with_(
-        n_layers=2, d_head=128)
+    cfg = configs.get(arch, smoke=True).with_(n_layers=2, d_head=d_head)
     params = jax.eval_shape(lambda k: lm.init(cfg, k)[0],
                             jax.random.PRNGKey(0))
     r, c = 4, 8
-    eng = Engine(cfg, params, EngineConfig(n_slots=r, n_blocks=64,
+    eng = Engine(cfg, params, EngineConfig(n_slots=r, n_blocks=128,
                                            block_size=BLOCK, max_len=128,
                                            prefill_chunk=c))
     args = jax.tree.map(
