@@ -7,10 +7,11 @@ in fixed-size blocks:
     k, v     (L, n_blocks, KV, block_size, W)       cfg.dtype | int8
     k_scale  (L, n_blocks, KV, block_size) f32      int8 mode only
 
-Head-major blocks: one head's (block_size, W) chunk is a whole tile of
-the ``flash_decode_paged`` kernel's BlockSpec, which addresses it by
-(layer, block). W is the head dim rounded up to the TPU's 128 lanes
-(``pool_width``): the kernel's tiles are lane-padded in any case, and
+Head-major blocks: one block of one layer, ``[layer, block]``, is a
+contiguous (KV, block_size, W) slab of whole tiles, which the
+``flash_decode_paged`` kernel copies by one DMA. W is the head dim
+rounded up to the TPU's 128 lanes (``pool_width``): the chip's DMA
+copies whole lane tiles, and
 a pool whose last dim is not a multiple of 128 is given a different
 memory layout by the TPU compiler (blocks minor, to save the padding),
 which it then copies to the kernel's layout and back at every step.
@@ -36,8 +37,8 @@ state and the step functions stay pure.
 Writes go through ``paged_write``: a scatter at ``(layer, block_id, :,
 offset)`` with ``mode="drop"`` so inactive rows (idle slots, exhausted
 prefill rows) write nowhere. Reads go
-through the ``flash_decode_paged`` kernel, whose BlockSpec index maps
-consume the block table as a scalar-prefetch operand.
+through the ``flash_decode_paged`` kernel, which takes the block table
+as a scalar-prefetch operand and copies each row's valid blocks.
 """
 from __future__ import annotations
 
@@ -99,7 +100,8 @@ def paged_cache_axes(cfg: ArchConfig) -> PagedKVCache:
     blocks are never sharded — any request may own any block, so a
     block dim split would scatter one stream across shards — while the
     KV-head dim TP-shards over "model" when it divides (each shard
-    serves its heads' pool; the flash-decode grid is per-kv-head)."""
+    serves its heads' pool; the flash-decode kernel copies a block's
+heads together, whatever their number)."""
     scale_ax = (("layers", "kv_blocks", "kv_heads", None)
                 if cfg.kv_quant else None)
     ax = ("layers", "kv_blocks", "kv_heads", None, None)

@@ -5,13 +5,15 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.flash_decode import (blocks_per_step, flash_decode,
+                                        flash_decode_paged)
 
 CASES = [  # (B, KV, G, dh, S, bs)
     (2, 4, 3, 32, 256, 64),
     (1, 8, 4, 64, 512, 128),
     (4, 2, 12, 64, 128, 128),    # qwen2-vl-like grouping, single chunk
     (2, 1, 1, 128, 256, 64),     # MQA
+    (2, 2, 2, 64, 1024, 512),    # the default 512-token blocks: one a step
 ]
 
 
@@ -143,36 +145,78 @@ def _stacked_pool(per_layer, bs_blk, n_blocks):
             jnp.stack([vp for _, vp, _ in pools]), pools[0][2])
 
 
-@pytest.mark.parametrize("layer", range(N_LAYERS))
-def test_flash_decode_paged_matches_ref(layer):
+def _lanes(t, w):
+    """Zero-pad the head dim to w lanes, as the paged cache's pool is."""
+    return jnp.pad(t, ((0, 0),) * (t.ndim - 1) + ((0, w - t.shape[-1]),))
+
+
+# (layer, kv, dh, w, bs, s, lengths): two query heads per KV head. The
+# first three are the pool's layers at P = 4 (s = 60, one group); with
+# 32-token blocks P = 8 (256 tokens a group) and the 19-block table is
+# not a multiple of it, nor is 16-token blocks' 19 one of P = 16.
+PAGED_CASES = {
+    "0": (0, 2, 32, 32, 16, 60, [60, 13, 1]),
+    "1": (1, 2, 32, 32, 16, 60, [60, 13, 1]),
+    "2": (2, 2, 32, 32, 16, 60, [60, 13, 1]),
+    # mid-group, at a group's end (P*bs) and one past it
+    "groups": (1, 2, 32, 32, 32, 600, [100, 256, 257]),
+    # one KV head, as a shard of a sharded pool holds; a full table
+    "kv1": (2, 1, 32, 32, 32, 600, [600, 32, 511]),
+    # head dim 160 in 256 lanes (zeros past it), P = 16
+    "w256-dh160": (0, 2, 160, 256, 16, 300, [300, 256, 17]),
+}
+
+
+@pytest.mark.parametrize("case", PAGED_CASES.values(), ids=PAGED_CASES)
+def test_flash_decode_paged_matches_ref(case):
     """Each layer of a stacked pool matches the reference on its own
     K/V; the other layers' blocks hold different values."""
-    b, kv, g, dh, s = 3, 2, 2, 32, 60
-    q = _mk((b, kv, g, dh, s, 16), seed=5)[0]
-    kvs = [_mk((b, kv, g, dh, s, 16), seed=5 + i)[1:3]
+    layer, kv, dh, w, bs, s, lens = case
+    b, g = len(lens), 2
+    q = _mk((b, kv, g, dh, s, bs), seed=5)[0]
+    kvs = [_mk((b, kv, g, dh, s, bs), seed=5 + i)[1:3]
            for i in range(N_LAYERS)]
-    lengths = jnp.array([60, 13, 1], jnp.int32)
-    kp, vp, bt = _stacked_pool(kvs, bs_blk=16, n_blocks=16)
+    lengths = jnp.array(lens, jnp.int32)
+    n_blocks = max(16, b * -(-s // bs))
+    kp, vp, bt = _stacked_pool([(_lanes(k, w), _lanes(v, w)) for k, v in kvs],
+                               bs_blk=bs, n_blocks=n_blocks)
     want = ref.flash_decode_ref(q, *kvs[layer], lengths)
-    got = flash_decode_paged(q, kp, vp, bt, lengths, layer, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    got = flash_decode_paged(_lanes(q, w), kp, vp, bt, lengths, layer,
+                             interpret=True)
+    np.testing.assert_allclose(np.asarray(got[..., :dh]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+    assert not np.any(np.asarray(got[..., dh:]))     # zeros past the head
 
 
-@pytest.mark.parametrize("layer", range(N_LAYERS))
-def test_flash_decode_paged_int8(layer):
-    b, kv, g, dh, s = 2, 2, 2, 32, 48
-    q = _mk((b, kv, g, dh, s, 16), seed=7)[0]
+# (layer, kv, bs, s, lengths); the first two are the pool's layers at one
+# group; "kv1-groups": one KV head (16 scale lanes of 128), P = 8, a
+# length at a group's end and one past the next group's start
+PAGED_INT8_CASES = {
+    "0": (0, 2, 16, 48, [48, 29]),
+    "1": (1, 2, 16, 48, [48, 29]),
+    "2": (2, 2, 16, 48, [48, 29]),
+    "kv1-groups": (1, 1, 32, 600, [256, 599]),
+}
+
+
+@pytest.mark.parametrize("case", PAGED_INT8_CASES.values(),
+                         ids=PAGED_INT8_CASES)
+def test_flash_decode_paged_int8(case):
+    layer, kv, bs, s, lens = case
+    b, g, dh = len(lens), 2, 32
+    q = _mk((b, kv, g, dh, s, bs), seed=7)[0]
     quant = [(_quant(k), _quant(v)) for k, v in (
-        _mk((b, kv, g, dh, s, 16), seed=7 + i)[1:3]
+        _mk((b, kv, g, dh, s, bs), seed=7 + i)[1:3]
         for i in range(N_LAYERS))]
-    lengths = jnp.array([48, 29], jnp.int32)
+    lengths = jnp.array(lens, jnp.int32)
     (kq, ks_), (vq, vs_) = quant[layer]
     want = ref.flash_decode_ref(q, kq, vq, lengths, ks_, vs_)
+    n_blocks = max(8, b * -(-s // bs))
     kp, vp, bt = _stacked_pool([(k[0], v[0]) for k, v in quant],
-                               bs_blk=16, n_blocks=8)
+                               bs_blk=bs, n_blocks=n_blocks)
     ksp, vsp, _ = _stacked_pool([(k[1][..., None], v[1][..., None])
-                                 for k, v in quant], bs_blk=16, n_blocks=8)
+                                 for k, v in quant], bs_blk=bs,
+                                n_blocks=n_blocks)
     got = flash_decode_paged(q, kp, vp, bt, lengths, layer,
                              ksp[..., 0], vsp[..., 0], interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -193,6 +237,66 @@ def test_flash_decode_paged_zero_length_rows(layer):
     want = np.asarray(ref.flash_decode_ref(q, *kvs[layer], lengths))
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
     assert np.array_equal(got[1], np.zeros_like(got[1]))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=("f32", "int8"))
+def test_flash_decode_paged_nothing_past_length(quant):
+    """Every block that is not among a row's first ceil(length / bs),
+    and every token past a row's length, holds NaN (scales too): the
+    output stays finite and matches the reference, so nothing past the
+    length reaches it. The table's entries past each length name
+    poisoned blocks."""
+    b, kv, g, dh, s, bs = 3, 2, 2, 32, 600, 32       # P = 8, 19 blocks
+    q, k, v, _ = _mk((b, kv, g, dh, s, bs), seed=11)
+    lengths = np.array([300, 0, 257], np.int32)
+    scales = (None, None)
+    if quant:
+        (k, k_s), (v, v_s) = _quant(k), _quant(v)
+        want = ref.flash_decode_ref(q, k, v, jnp.asarray(lengths), k_s, v_s)
+        ksp, vsp, _ = _scatter_to_pool(k_s[..., None], v_s[..., None], bs,
+                                       64)
+        scales = (ksp[None, ..., 0], vsp[None, ..., 0])
+    else:
+        want = ref.flash_decode_ref(q, k, v, jnp.asarray(lengths))
+    kp, vp, bt = _scatter_to_pool(k, v, bs, 64)
+    bt = np.asarray(bt)
+    live = np.zeros(64, bool)
+    for r, n in enumerate(lengths):
+        live[bt[r, :-(-n // bs)]] = True
+    tok = np.arange(bs)
+
+    def poison(pool, dt):
+        pool = np.asarray(pool, np.float32).copy()
+        pool[~live] = np.nan
+        for r, n in enumerate(lengths):
+            if n % bs:                  # the tokens past n in its block
+                pool[bt[r, n // bs]][..., tok >= n % bs, :] = np.nan
+        return jnp.asarray(pool, dt)
+
+    if quant:           # int8 holds no NaN: poison the scales instead
+        kp, vp = kp[None], vp[None]
+        scales = tuple(poison(t[0][..., None], jnp.float32)[None, ..., 0]
+                       for t in scales)
+    else:
+        kp, vp = poison(kp, kp.dtype)[None], poison(vp, vp.dtype)[None]
+    got = np.asarray(flash_decode_paged(q, kp, vp, jnp.asarray(bt),
+                                        jnp.asarray(lengths), 0, *scales,
+                                        interpret=True))
+    assert np.all(np.isfinite(got))
+    live_rows = lengths > 0
+    np.testing.assert_allclose(got[live_rows], np.asarray(want)[live_rows],
+                               rtol=1e-5, atol=1e-5)
+    assert not np.any(got[~live_rows])
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8, 16, 128, 2, 160), 16),        # Mistral-NeMo's chat pool, bf16
+    ((8, 16, 256, 2, 160), 16),        # StableLM-2's, 160 in 256 lanes
+    ((8, 512, 128, 2, 4), 1),          # flash_decode's 512-token blocks
+    ((2, 16, 128, 1, 5), 5),           # a table shorter than a group
+], ids=("mistral", "stablelm", "contiguous", "short-table"))
+def test_blocks_per_step(shape, want):
+    assert blocks_per_step(*shape) == want
 
 
 def test_flash_decode_respects_length():

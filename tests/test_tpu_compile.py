@@ -4,9 +4,11 @@ v5e chip.
 Nothing runs: each kernel is lowered and compiled by the TPU compiler
 for a *described* v5e chip (no chip attached) at StableLM-2-12B widths
 — d_model 5120, d_ff 13824, 8 KV heads x 160, 16-token KV blocks, the
-engine's 8 rows per matmul. This is what interpret-mode tests cannot
-see: block shapes the Mosaic tiling refuses, layouts it cannot lower,
-kernels over the VMEM limit, and copies the compiler adds around them.
+engine's 8 rows per matmul — and the paged attention kernel at the chat
+cells' pool and block table, head widths 128 and 256 lanes. This is
+what interpret-mode tests cannot see: block shapes the Mosaic tiling
+refuses, layouts it cannot lower, kernels over the VMEM limit, and
+copies the compiler adds around them.
 The topology is described inside a module fixture, so only the worker
 that runs this file loads the TPU compiler; everything built from it
 lives in fixtures or tests too.
@@ -32,7 +34,7 @@ from repro.serving.engine import pool_aliases
 
 D_MODEL, D_FF = 5120, 13824
 N_KV, GROUP, D_HEAD, BLOCK = 8, 4, 160, 16
-ROWS, N_BLOCKS, TABLE = 8, 144, 18        # 8 slots x 18 blocks of 16
+ROWS = 8                                  # rows of a packed matmul
 N_LAYERS = 5
 
 
@@ -67,14 +69,34 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=("bf16", "int8"))
-def test_flash_decode_paged_compiles(one_chip, quant):
-    """The kernel reads one layer of the stacked pool in place."""
+def _operand_shapes_text(compiled):
+    """The compiled module printed with each operand's shape, as the
+    device trace names an op."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+
+
+# the chat cells: 32 slots x 160 blocks of 16 over 5 layers of 5,120
+# blocks; head dim 128 (Mistral-NeMo) or 160 stored in 256 lanes
+# (StableLM-2, the pool's lane padding)
+CELL_ROWS, CELL_TABLE, CELL_BLOCKS = 32, 160, 5120
+
+
+@pytest.mark.parametrize("quant,width", [(False, 256), (True, 256),
+                                         (False, 128), (True, 128)],
+                         ids=("bf16", "int8", "bf16-w128", "int8-w128"))
+def test_flash_decode_paged_compiles(one_chip, quant, width):
+    """The kernel at the chat cells' shapes reads one layer of the
+    stacked pool in place: it fits VMEM (the compiler refuses a kernel
+    whose scratch does not), and its op keeps the block table as its
+    first operand, which is how the benchmark finds it in the trace."""
     kv_dt = jnp.int8 if quant else jnp.bfloat16
-    pool = (N_LAYERS, N_BLOCKS, N_KV, BLOCK, D_HEAD)
-    shapes = [((ROWS, N_KV, GROUP, D_HEAD), jnp.bfloat16),
+    pool = (N_LAYERS, CELL_BLOCKS, N_KV, BLOCK, width)
+    shapes = [((CELL_ROWS, N_KV, GROUP, width), jnp.bfloat16),
               (pool, kv_dt), (pool, kv_dt),
-              ((ROWS, TABLE), jnp.int32), ((ROWS,), jnp.int32),
+              ((CELL_ROWS, CELL_TABLE), jnp.int32), ((CELL_ROWS,), jnp.int32),
               ((), jnp.int32)]
     if quant:
         shapes += [(pool[:-1], jnp.float32)] * 2
@@ -83,6 +105,9 @@ def test_flash_decode_paged_compiles(one_chip, quant):
     # the device trace names the kernel's op after the pallas_call
     assert re.search(r"^\s*(ROOT )?%flash_decode_paged(\.\d+)? = ",
                      compiled.as_text(), re.M)
+    # the benchmark's pattern for the kernel (chipbench/metrics/paged_attn*)
+    assert re.search(r"custom-call\(s32\[%d,%d\]" % (CELL_ROWS, CELL_TABLE),
+                     _operand_shapes_text(compiled))
     # no layer's pool is sliced out of the stacked one to be read
     assert not re.search(r"= \S+\[%d,%d,%d,%d\]" % pool[1:],
                          compiled.as_text())
