@@ -18,8 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing, slab
+from repro.core.packed_model import pack_linear, packed_matmul
 from repro.core.slab import SLaBConfig
-from repro.kernels import ops, ref
 from benchmarks.common import emit
 
 SHAPES = [(512, 2048, 2048), (256, 4096, 4096)]
@@ -47,15 +47,15 @@ def run():
         for pattern in (None, "2:4"):
             dec = slab.slab_decompose(
                 w, None, SLaBConfig(cr=0.5, iters=2, pattern=pattern))
-            pk = packing.pack_decomposition(dec, pattern=pattern)
-            got = ops.slab_linear_kernel(x, pk, bm=128, bn=128, bk=256,
-                                         interpret=True)
+            pk = pack_linear(dec, pattern)
+            got = packed_matmul(x, pk, interpret=True)
             want = x @ slab.reconstruct(dec).T
             err = float(jnp.max(jnp.abs(got - want)))
             bits = weight_stream_bits(dec, pattern)
             rows.append({
                 "shape": f"{m}x{n}x{k}",
                 "pattern": pattern or "unstructured",
+                "variant": pk.variant,
                 "max_err_vs_dense_reconstruction": err,
                 "bits_per_weight_streamed": round(bits, 2),
                 "dense_bits": 16,

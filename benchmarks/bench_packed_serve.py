@@ -100,7 +100,7 @@ def _synthetic_packed(cfg):
 
 def _moe_row():
     """Expert-packed MoE vs dense: decode tok/s plus the bytes of the
-    three 3-D expert leaves served by the grouped-expert kernels (the
+    three 3-D expert leaves served by the expert-packed kernels (the
     dense islands the expert-axis PackedStack finally packed)."""
     cfg = configs.get(MOE_ARCH, smoke=True).with_(dtype=jnp.float32)
     params, _ = lm.init(cfg, jax.random.PRNGKey(1))

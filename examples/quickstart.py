@@ -6,11 +6,11 @@ piece of the paper's Eq. (1): W ≈ W_S + W_L ⊙ W_B.
 import jax
 import jax.numpy as jnp
 
-from repro.core import packing, scores
+from repro.core import scores
 from repro.core.apply import slab_linear
+from repro.core.packed_model import pack_linear, packed_matmul
 from repro.core.slab import (SLaBConfig, compression_ratio, keep_fraction,
                              reconstruct, slab_decompose)
-from repro.kernels import ops
 
 # A fake "linear layer" weight and its calibration activations.
 d_out, d_in = 512, 1024
@@ -54,10 +54,10 @@ print(f"registry slab:          measured CR {cl.cr:.4f}, "
 x = jax.random.normal(jax.random.PRNGKey(2), (8, d_in))
 y_ref = x @ reconstruct(dec).T
 y_jnp = slab_linear(x, dec)                          # XLA path
-pk = packing.pack_decomposition(dec)                 # bit-packed form
-y_kern = ops.slab_linear_kernel(x, pk, bm=8, bn=128, bk=256,
-                                interpret=True)      # Pallas kernel
+pk = pack_linear(dec, None)                          # packed serving form
+y_kern = packed_matmul(x, pk, interpret=True)        # Pallas kernel
 print(f"XLA path max err:       {float(jnp.max(jnp.abs(y_jnp - y_ref))):.2e}")
 print(f"Pallas kernel max err:  {float(jnp.max(jnp.abs(y_kern - y_ref))):.2e}")
+print(f"packed variant:         {pk.variant}")
 print(f"packed B matrix:        {pk.b_packed.shape} uint32 "
       f"(16x smaller than bf16)")

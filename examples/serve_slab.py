@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.core import packing
+from repro.core.packed_model import pack_linear
 from repro.core.pipeline import compress_model, linear_paths
 from repro.core.slab import SLaBConfig, slab_decompose
 from repro.data import SyntheticCorpus, calibration_batch
@@ -38,7 +38,7 @@ def main():
     # storage accounting on one layer's wq
     w = params["layers"]["attn"]["wq"][0].T.astype(jnp.float32)
     dec = slab_decompose(w, None, SLaBConfig(cr=0.5, iters=8))
-    pk = packing.pack_decomposition(dec)
+    pk = pack_linear(dec, None)
     dense_bytes = w.size * 2
     nnz = int(jnp.sum(dec.w_s != 0))
     packed_bytes = nnz * 2 + pk.b_packed.size * 4 + (pk.u.size + pk.v.size) * 2

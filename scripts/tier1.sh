@@ -16,7 +16,7 @@
 #                               # packed forward/decode (full-depth
 #                               # trace-count cases stay @slow)
 #   scripts/tier1.sh moe        # expert-packed MoE loop: K_max
-#                               # bucketing, grouped-expert kernels,
+#                               # bucketing, vmapped expert kernels,
 #                               # dense-member fallbacks, MoE/hybrid
 #                               # shared-block parity (engine replay +
 #                               # deepseek geometry stay @slow)

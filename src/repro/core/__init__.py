@@ -9,7 +9,7 @@ from repro.core.slab import (  # noqa: F401
     reconstruct,
     slab_decompose,
 )
-from repro.core.apply import slab_linear, slab_linear_packed, to_dense  # noqa: F401
+from repro.core.apply import slab_linear, to_dense  # noqa: F401
 from repro.core.compressor import (  # noqa: F401
     CompressedLinear,
     Compressor,

@@ -6,17 +6,14 @@ The rank-1 Hadamard structure gives the cheap serving identity
 
 so a compressed linear needs one sparse matmul + one binary matmul + two
 vector scalings. The Pallas kernels in ``repro.kernels`` implement the
-packed/tiled versions; these jnp forms are the oracles and the XLA
-fallback used by the serving path when kernels are disabled.
+packed/tiled versions (``core.packed_model``); these jnp forms are the
+oracles that path is tested against.
 """
 from __future__ import annotations
-
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.packing import ELLPacked, NMPacked, SLaBPacked, unpack_nm, unpack_sign_bits
 from repro.core.slab import SLaBDecomposition, low_rank_times_binary
 
 Array = jax.Array
@@ -40,26 +37,6 @@ def slab_linear(x: Array, dec: SLaBDecomposition) -> Array:
     elif has_lr or has_b:
         y = y + x @ low_rank_times_binary(dec).astype(dt).T
     return y
-
-
-def slab_linear_packed(x: Array, p: SLaBPacked) -> Array:
-    """Forward from the packed (serving) format — jnp reference path that
-    unpacks on the fly; the Pallas kernel does the same tile-wise in VMEM."""
-    dt = x.dtype
-    if isinstance(p.sparse, NMPacked):
-        w_s = unpack_nm(p.sparse)
-    elif isinstance(p.sparse, ELLPacked):
-        from repro.core.packing import ell_unpack
-        w_s = ell_unpack(p.sparse)
-    else:
-        w_s = p.sparse
-    b = unpack_sign_bits(p.b_packed, p.d_in, dtype=dt)
-    y = x @ w_s.astype(dt).T
-    return y + ((x * p.v.astype(dt)) @ b.T) * p.u.astype(dt)
-
-
-class DenseEquivalent(NamedTuple):
-    w: Array
 
 
 def to_dense(dec: SLaBDecomposition, dtype=jnp.bfloat16) -> Array:

@@ -233,9 +233,9 @@ class ExpertPackedStack:
     (``pack_expert_stack``) and each bucket pads to ITS realized max —
     ragged experts never pad to the global max. ``dense`` holds the
     original model-orientation ``(E_d, D_in, D_out)`` slices for
-    experts with no packable terms. One grouped-kernel launch serves a
-    whole bucket (``expert_matmul``), with the expert index leading the
-    Pallas grid (kernels.grouped).
+    experts with no packable terms. One kernel launch serves a whole
+    bucket (``expert_matmul``: the per-linear kernel under ``jax.vmap``,
+    the expert index leading the Pallas grid).
 
     Layer stacking is structural: a stacked ExpertPackedStack simply
     carries an extra leading layer dim on every child (groups' planes
@@ -688,73 +688,30 @@ def _packed_matmul_local(x: Array, w: PackedLinear,
     return y.astype(x.dtype)
 
 
-def packed_matmul_grouped(x: Array, w: PackedLinear,
-                          interpret: Optional[bool] = None) -> Array:
-    """x (E, M, D_in) against an expert-stacked PackedLinear (every
-    plane leads with E) -> (E, M, D_out), one grouped-kernel launch
-    with the expert index leading the Pallas grid (kernels.grouped)."""
-    from repro.kernels import ops
-    var = w.variant
-    bn, bk = _kernel_blocks(w)
-    if var.endswith("-ell"):
-        kw = dict(bm=128, bn=bn, interpret=interpret)
-        if var == "sparse-ell":
-            y = ops.ell_matmul_g(x, w.sparse_vals, w.sparse_idx, **kw)
-        elif var == "lowrank-ell":
-            y = ops.ell_lr_matmul_g(x, w.sparse_vals, w.sparse_idx,
-                                    w.u, w.v, **kw)
-        else:
-            y = ops.slab_ell_matmul_g(x, w.sparse_vals, w.sparse_idx,
-                                      w.b_packed, w.u, w.v, **kw)
-        return y.astype(x.dtype)
-    kw = dict(bm=128, bn=bn, bk=bk, interpret=interpret)
-    if var == "slab-nm":
-        y = ops.slab_nm_matmul_g(x, w.sparse_vals, w.sparse_idx, w.m_pat,
-                                 w.b_packed, w.u, w.v, **kw)
-    elif var == "slab-dense":
-        y = ops.slab_matmul_g(x, w.sparse_vals.astype(x.dtype),
-                              w.b_packed, w.u, w.v, **kw)
-    elif var == "binlr":
-        y = ops.binlr_g(x, w.b_packed, w.u, w.v, **kw)
-    elif var == "lowrank-nm":
-        y = ops.slab_nm_lr_matmul_g(x, w.sparse_vals, w.sparse_idx,
-                                    w.m_pat, w.u, w.v, **kw)
-    elif var == "lowrank-dense":
-        y = ops.slab_lr_matmul_g(x, w.sparse_vals.astype(x.dtype),
-                                 w.u, w.v, **kw)
-    elif var == "lowrank":
-        # two skinny batched XLA matmuls — already minimal bytes
-        y = jnp.einsum("emk,ekr->emr", x.astype(jnp.float32),
-                       w.v.astype(jnp.float32))
-        y = jnp.einsum("emr,enr->emn", y, w.u.astype(jnp.float32))
-    elif var == "sparse-nm":
-        y = ops.nm_matmul_g(x, w.sparse_vals, w.sparse_idx, w.m_pat, **kw)
-    elif var == "sparse-dense":
-        y = jnp.einsum("emk,enk->emn", x, w.sparse_vals.astype(x.dtype))
-    else:
-        raise ValueError(f"unknown packed variant {var!r}")
-    return y.astype(x.dtype)
-
-
 def expert_matmul(x: Array, w: ExpertPackedStack,
                   interpret: Optional[bool] = None) -> Array:
     """Per-expert packed linear: x (E, M, D_in) -> (E, M, D_out).
 
-    One grouped-kernel launch per expert BUCKET: experts of a bucket
-    share packed shapes (same variant / rank / ELL pad width), so each
-    launch streams a contiguous (E_g, ...) plane stack. Expert ids are
-    static aux, so the bucket gathers/reorder resolve to constant-index
-    gathers at trace time; the common all-in-one-bucket case skips them
-    entirely."""
+    One kernel launch per expert BUCKET: experts of a bucket share
+    packed shapes (same variant / rank / ELL pad width), so each launch
+    streams a contiguous (E_g, ...) plane stack. The launch is the
+    per-linear dispatch under ``jax.vmap`` over the bucket's leading
+    expert dim: Pallas's batching rule puts the expert index at the
+    front of the kernel's grid, and the static aux rides through as it
+    does for ``lax.scan``. Expert ids are static aux, so the bucket
+    gathers/reorder resolve to constant-index gathers at trace time;
+    the common all-in-one-bucket case skips them entirely."""
+    per_expert = jax.vmap(
+        lambda xx, ww: _packed_matmul_local(xx, ww, interpret))
     n = w.n_experts
     if (len(w.groups) == 1 and not w.dense_members
             and w.members[0] == tuple(range(n))):
-        return packed_matmul_grouped(x, w.groups[0], interpret)
+        return per_expert(x, w.groups[0])
     parts: List[Array] = []
     order: List[int] = []
     for mem, grp in zip(w.members, w.groups):
         xg = jnp.take(x, jnp.asarray(mem), axis=0)
-        parts.append(packed_matmul_grouped(xg, grp, interpret))
+        parts.append(per_expert(xg, grp))
         order.extend(mem)
     if w.dense is not None:
         xd = jnp.take(x, jnp.asarray(w.dense_members), axis=0)
@@ -856,7 +813,7 @@ def _leaf_signature(leaf) -> Tuple:
 # How many quantization buckets the per-expert realized ELL K_max is
 # split into: within a bucket experts pad to the bucket's realized max,
 # so a few hot experts don't inflate every expert's pad width, while
-# the number of grouped-kernel launches stays bounded.
+# the number of per-bucket kernel launches stays bounded.
 EXPERT_KMAX_BUCKETS = 4
 
 
@@ -875,7 +832,7 @@ def pack_expert_stack(old: Array,
     row-nnz K_max + total nnz); ELL experts then bucket by quantized
     K_max — bucket width ``ceil(global_max / n_buckets)`` — and every
     bucket pads to its own realized max. Experts sharing a full packed
-    signature stack into one grouped-kernel launch."""
+    signature stack into one kernel launch (``expert_matmul``)."""
     n_exp = len(e_decs)
     itemsize = jnp.dtype(dtype).itemsize
     # experts with no sparse plane at all (w_s=None decs) can't join the
@@ -979,7 +936,7 @@ def pack_plan_decs(params: dict,
 
     3-D MoE leaves arrive as TUPLES of per-expert decs (the pipeline's
     expert branch) and pack into per-layer ``ExpertPackedStack``s
-    (K_max-bucketed grouped-kernel launches); hybrid shared-block decs
+    (one kernel launch per K_max bucket); hybrid shared-block decs
     arrive under ``shared.*`` names (keyed at the firing layer) and
     pack once into ``params["shared_attn"]``. Still-dense bytes —
     unservable decs, plan-uncovered layers of packed paths, and

@@ -167,28 +167,3 @@ def ell_unpack(p: ELLPacked) -> Array:
     out = jnp.zeros((d_out, p.d_in), p.values.dtype)
     return out.at[rows, p.indices.astype(jnp.int32)].add(p.values)
 
-
-# --------------------------- SLaB packed bundle ------------------------
-
-class SLaBPacked(NamedTuple):
-    """On-HBM serving format of one compressed linear layer."""
-    sparse: NMPacked | ELLPacked | Array  # dense-masked fallback is a raw Array
-    u: Array
-    v: Array
-    b_packed: Array  # uint32 (Di/32, Do)
-    d_out: int
-    d_in: int
-
-
-def pack_decomposition(dec, pattern: str | None = None) -> SLaBPacked:
-    from repro.core import sparsity as sp
-    d_out, d_in = dec.w_s.shape
-    if pattern is not None:
-        n, m = sp.parse_pattern(pattern)
-        sparse = pack_nm(dec.w_s, n, m)
-    else:
-        nnz = sp.mask_nnz_per_row_uniform(dec.w_s != 0)
-        sparse = ell_pack(dec.w_s, nnz) if nnz is not None else dec.w_s
-    u = dec.u[:, 0] if dec.u.ndim == 2 and dec.u.shape[1] == 1 else dec.u
-    v = dec.v[:, 0] if dec.v.ndim == 2 and dec.v.shape[1] == 1 else dec.v
-    return SLaBPacked(sparse, u, v, pack_sign_bits(dec.w_b), d_out, d_in)

@@ -324,7 +324,7 @@ def _compress_leaf(layer: int, pth: str, w: Array, an: Optional[Array],
         w_new = jnp.stack(outs)
         cr = float(np.mean(crs)) if crs else comp.scfg.cr
         # the per-expert decs travel as a tuple — pack_plan_decs routes
-        # 3-D leaves to pack_expert_stack (expert-axis grouped kernels)
+        # 3-D leaves to pack_expert_stack (expert-axis kernel launches)
         dec = tuple(e_decs) if all(d is not None for d in e_decs) else None
         st = CompressStats(layer, pth, float(np.sqrt(eb2)),
                            float(np.sqrt(ea2)), cr, r.method,

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the SLaB Pallas kernels.
 
-Handles shape padding to block multiples, dtype plumbing, the
-interpret-mode switch (CPU validation; compiled Mosaic on real TPU), and
-a `slab_linear_kernel` convenience that consumes a `SLaBPacked` bundle.
+Handles shape padding to block multiples, dtype plumbing, and the
+interpret-mode switch (CPU validation; compiled Mosaic on real TPU).
+The packed serving path (``core.packed_model``) calls these per linear,
+and under ``jax.vmap`` per expert bucket.
 
 Low-rank factors are accepted in any of the storage conventions —
 ``u``: (N,) rank-1 vector or (N, R) column factors; ``v``: (K,) or
@@ -17,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.packing import NMPacked, SLaBPacked
 from repro.kernels import binlr as binlr_k
 from repro.kernels import ell as ell_k
 from repro.kernels import nm_sparse as nm_k
@@ -189,157 +189,6 @@ def slab_ell_matmul(x: Array, vals: Array, idx: Array, b_packed: Array,
     return y[:m].reshape(*lead, -1)
 
 
-# ------------------- grouped-expert (MoE) wrappers ---------------------
-#
-# x carries a leading expert dim (E, M, K) — the flattened post-dispatch
-# capacity buffer — and every weight plane is expert-stacked. Token
-# padding happens on axis 1; the expert axis is never padded (one grid
-# step per expert).
-
-def _pad_tokens_g(x: Array, mult: int) -> Array:
-    m = x.shape[1]
-    pad = (-m) % mult
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    return x
-
-
-def _rank_stack_g(u: Array, v: Array):
-    """Expert-stacked (E,N,R) u / (E,K,R) v -> kernel layout (E,R,N) /
-    (E,R,K)."""
-    return u.transpose(0, 2, 1), v.transpose(0, 2, 1)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def ell_matmul_g(x: Array, vals: Array, idx: Array,
-                 bm: int = 128, bn: int = 256,
-                 interpret: Optional[bool] = None) -> Array:
-    """Grouped-expert ELL matmul: x (E, M, K), vals/idx (E, N, K_max)."""
-    interpret = _on_cpu() if interpret is None else interpret
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.ell_matmul_g(x2, vals, idx, bm=bm, bn=bn, interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def ell_lr_matmul_g(x: Array, vals: Array, idx: Array, u: Array, v: Array,
-                    bm: int = 128, bn: int = 256,
-                    interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.ell_lr_matmul_g(x2, vals, idx, u, v, bm=bm, bn=bn,
-                            interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def slab_ell_matmul_g(x: Array, vals: Array, idx: Array, b_packed: Array,
-                      u: Array, v: Array,
-                      bm: int = 128, bn: int = 256,
-                      interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.slab_ell_matmul_g(x2, vals, idx, b_packed, u, v, bm=bm, bn=bn,
-                              interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("m_pat", "bm", "bn", "bk", "interpret"))
-def nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
-                bm: int = 256, bn: int = 256, bk: int = 512,
-                interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.nm_matmul_g(x2, vals, idx, m_pat, bm=bm, bn=bn, bk=bk,
-                        interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def slab_matmul_g(x: Array, w_s: Array, b_packed: Array, u: Array, v: Array,
-                  bm: int = 256, bn: int = 256, bk: int = 512,
-                  interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.slab_matmul_g(x2, w_s, b_packed, u, v, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("m_pat", "bm", "bn", "bk", "interpret"))
-def slab_nm_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
-                     b_packed: Array, u: Array, v: Array,
-                     bm: int = 256, bn: int = 256, bk: int = 512,
-                     interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.slab_nm_matmul_g(x2, vals, idx, m_pat, b_packed, u, v,
-                             bm=bm, bn=bn, bk=bk, interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def slab_lr_matmul_g(x: Array, w_s: Array, u: Array, v: Array,
-                     bm: int = 256, bn: int = 256, bk: int = 512,
-                     interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.slab_lr_matmul_g(x2, w_s, u, v, bm=bm, bn=bn, bk=bk,
-                             interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("m_pat", "bm", "bn", "bk", "interpret"))
-def slab_nm_lr_matmul_g(x: Array, vals: Array, idx: Array, m_pat: int,
-                        u: Array, v: Array,
-                        bm: int = 256, bn: int = 256, bk: int = 512,
-                        interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.slab_nm_lr_matmul_g(x2, vals, idx, m_pat, u, v,
-                                bm=bm, bn=bn, bk=bk, interpret=interpret)
-    return y[:, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def binlr_g(x: Array, b_packed: Array, u: Array, v: Array,
-            bm: int = 256, bn: int = 256, bk: int = 512,
-            interpret: Optional[bool] = None) -> Array:
-    interpret = _on_cpu() if interpret is None else interpret
-    u, v = _rank_stack_g(u, v)
-    m = x.shape[1]
-    x2 = _pad_tokens_g(x, min(bm, max(m, 1)))
-    from repro.kernels import grouped as g_k
-    y = g_k.binlr_matmul_g(x2, b_packed, u, v, bm=bm, bn=bn, bk=bk,
-                           interpret=interpret)
-    return y[:, :m]
-
-
 def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
                                  block_tables: Array, lengths: Array, layer,
                                  k_scale: Optional[Array] = None,
@@ -381,17 +230,3 @@ def flash_decode_paged_attention(q: Array, k_pool: Array, v_pool: Array,
             out_specs=heads, check_vma=False)(*args)
     return out[..., :dh] if pad else out
 
-
-def slab_linear_kernel(x: Array, packed: SLaBPacked, **kw) -> Array:
-    """Forward one SLaB-compressed linear from its packed bundle via the
-    fused kernel (N:M if the sparse part is N:M packed, else dense)."""
-    if isinstance(packed.sparse, NMPacked):
-        s = packed.sparse
-        return slab_nm_matmul(x, s.values, s.indices, s.m,
-                              packed.b_packed, packed.u, packed.v, **kw)
-    w_s = packed.sparse if isinstance(packed.sparse, jax.Array) else None
-    if w_s is None:
-        from repro.core.packing import ell_unpack
-        w_s = ell_unpack(packed.sparse)
-    return slab_matmul(x, w_s.astype(x.dtype), packed.b_packed,
-                       packed.u, packed.v, **kw)

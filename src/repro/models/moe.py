@@ -32,8 +32,9 @@ def _expert_apply(x4: Array, w) -> Array:
     """Batched per-expert linear on the post-dispatch buffer: x4
     (G, E, C, D_in) -> (G, E, C, D_out). ``w`` is either the dense
     (E, D_in, D_out) expert leaf (einsum — XLA batched matmul) or an
-    ``ExpertPackedStack``, served by the grouped-expert Pallas kernels
-    (one launch per expert bucket, expert index in the grid)."""
+    ``ExpertPackedStack``, served by the per-linear Pallas kernels under
+    ``jax.vmap`` (one launch per expert bucket, expert index in the
+    grid)."""
     if isinstance(w, ExpertPackedStack):
         g, e, c, d = x4.shape
         xe = x4.transpose(1, 0, 2, 3).reshape(e, g * c, d)

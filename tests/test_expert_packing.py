@@ -1,6 +1,6 @@
 """Expert-axis packed serving: 3-D MoE leaves pack into K_max-bucketed
-ExpertPackedStacks served by the grouped-expert fused kernels (interpret
-mode on CPU), the hybrid shared block packs into plain PackedLinears,
+ExpertPackedStacks served by the per-linear fused kernels under
+``jax.vmap`` (interpret mode on CPU), the hybrid shared block packs into plain PackedLinears,
 and end-to-end MoE traces — including PR-7 engine eviction replay —
 stay token-exact against the dense model."""
 import jax
@@ -157,7 +157,7 @@ def test_single_bucket_full_coverage_fast_path():
 def test_moe_packs_every_expert_zero_fallback(moe_setup):
     """The acceptance property: a full-coverage slab plan on an MoE
     model leaves NO dense-fallback linears — every expert of every 3-D
-    leaf serves on a grouped kernel."""
+    leaf serves on a packed kernel."""
     cfg, _, packed, rep, stats, _, _ = moe_setup
     assert rep.fallback == []
     n_expert = len(EXPERT_PATHS) * cfg.n_layers * cfg.n_experts
@@ -186,7 +186,7 @@ def test_moe_forward_matches_dense(moe_setup):
 
 
 def test_moe_greedy_decode_token_exact(moe_setup):
-    """Greedy decode through the grouped-expert kernels emits the SAME
+    """Greedy decode through the expert-packed kernels emits the SAME
     tokens as the dense-applied model at f32."""
     cfg, dense_c, packed, _, _, _, _ = moe_setup
     prompts = jax.random.randint(jax.random.PRNGKey(2), (2, 8), 0,
@@ -345,7 +345,7 @@ def test_shared_block_decode_matches_dense(zamba_setup):
 
 
 # ------------------------------------------------------------------
-# PR-7 engine: eviction replay through the grouped-expert kernels
+# PR-7 engine: eviction replay through the expert-packed kernels
 # ------------------------------------------------------------------
 
 @pytest.mark.slow

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import packing, slab
+from repro.core.packed_model import pack_linear, packed_matmul
 from repro.core.slab import SLaBConfig
 from repro.kernels import ops, ref
 
@@ -35,7 +36,7 @@ def test_binlr_matches_ref(shape, dtype):
     m, n, k, bm, bn, bk = shape
     x, w = _mk(0, m, n, k, dtype)
     dec = slab.slab_decompose(w, None, SLaBConfig(cr=0.5, iters=2))
-    pk = packing.pack_decomposition(dec)
+    pk = pack_linear(dec, None)
     want = ref.binlr_ref(x.astype(jnp.float32), pk.b_packed, pk.u, pk.v)
     got = ops.binlr(x, pk.b_packed, pk.u, pk.v, bm=bm, bn=bn, bk=bk,
                     interpret=True)
@@ -50,11 +51,10 @@ def test_nm_matmul_matches_ref(shape, pattern):
     x, w = _mk(1, m, n, k, jnp.float32)
     dec = slab.slab_decompose(w, None,
                               SLaBConfig(cr=0.5, iters=2, pattern=pattern))
-    pk = packing.pack_decomposition(dec, pattern=pattern)
-    s = pk.sparse
-    want = ref.nm_matmul_ref(x, s.values, s.indices, s.m)
-    got = ops.nm_matmul(x, s.values, s.indices, s.m, bm=bm, bn=bn, bk=bk,
-                        interpret=True)
+    pk = pack_linear(dec, pattern)
+    want = ref.nm_matmul_ref(x, pk.sparse_vals, pk.sparse_idx, pk.m_pat)
+    got = ops.nm_matmul(x, pk.sparse_vals, pk.sparse_idx, pk.m_pat,
+                        bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -65,7 +65,7 @@ def test_slab_matmul_fused_matches_ref(shape, dtype):
     m, n, k, bm, bn, bk = shape
     x, w = _mk(2, m, n, k, dtype)
     dec = slab.slab_decompose(w, None, SLaBConfig(cr=0.5, iters=2))
-    pk = packing.pack_decomposition(dec)
+    pk = pack_linear(dec, None)
     ws = dec.w_s.astype(dtype)
     want = ref.slab_matmul_ref(x.astype(jnp.float32),
                                dec.w_s, pk.b_packed, pk.u, pk.v)
@@ -81,11 +81,10 @@ def test_slab_nm_matmul_matches_ref(shape):
     x, w = _mk(3, m, n, k, jnp.float32)
     dec = slab.slab_decompose(w, None,
                               SLaBConfig(cr=0.5, iters=2, pattern="2:4"))
-    pk = packing.pack_decomposition(dec, pattern="2:4")
-    s = pk.sparse
-    want = ref.slab_nm_matmul_ref(x, s.values, s.indices, s.m,
-                                  pk.b_packed, pk.u, pk.v)
-    got = ops.slab_nm_matmul(x, s.values, s.indices, s.m,
+    pk = pack_linear(dec, "2:4")
+    want = ref.slab_nm_matmul_ref(x, pk.sparse_vals, pk.sparse_idx,
+                                  pk.m_pat, pk.b_packed, pk.u, pk.v)
+    got = ops.slab_nm_matmul(x, pk.sparse_vals, pk.sparse_idx, pk.m_pat,
                              pk.b_packed, pk.u, pk.v,
                              bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -96,10 +95,8 @@ def test_kernel_vs_dense_reconstruction():
     """End to end: fused kernel == x @ Ŵᵀ for the real decomposition."""
     x, w = _mk(4, 64, 128, 256, jnp.float32)
     dec = slab.slab_decompose(w, None, SLaBConfig(cr=0.5, iters=4))
-    pk = packing.pack_decomposition(dec)
     dense = x @ slab.reconstruct(dec).T
-    got = ops.slab_linear_kernel(x, pk, bm=32, bn=64, bk=64,
-                                 interpret=True)
+    got = packed_matmul(x, pack_linear(dec, None), interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                rtol=1e-4, atol=1e-4)
 
@@ -159,12 +156,12 @@ def test_slab_nm_lr_matmul_matches_ref(rank):
     x, w = _mk(13, m, n, k, jnp.float32)
     dec = slab.slab_decompose(w, None,
                               SLaBConfig(cr=0.5, iters=2, pattern="2:4"))
-    pk = packing.pack_decomposition(dec, pattern="2:4")
-    s = pk.sparse
+    pk = pack_linear(dec, "2:4")
     u, v = _rank_factors(14, n, k, rank)
-    want = ref.slab_nm_lr_matmul_ref(x, s.values, s.indices, s.m, u, v)
-    got = ops.slab_nm_lr_matmul(x, s.values, s.indices, s.m, u, v,
-                                bm=bm, bn=bn, bk=bk, interpret=True)
+    want = ref.slab_nm_lr_matmul_ref(x, pk.sparse_vals, pk.sparse_idx,
+                                     pk.m_pat, u, v)
+    got = ops.slab_nm_lr_matmul(x, pk.sparse_vals, pk.sparse_idx, pk.m_pat,
+                                u, v, bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -174,7 +171,7 @@ def test_batched_leading_dims():
     x3 = jax.random.normal(jax.random.PRNGKey(5), (4, 8, 128), jnp.float32)
     _, w = _mk(6, 1, 64, 128, jnp.float32)
     dec = slab.slab_decompose(w, None, SLaBConfig(cr=0.5, iters=2))
-    pk = packing.pack_decomposition(dec)
+    pk = pack_linear(dec, None)
     got = ops.slab_matmul(x3, dec.w_s, pk.b_packed, pk.u, pk.v,
                           bm=32, bn=32, bk=64, interpret=True)
     assert got.shape == (4, 8, 64)

@@ -10,7 +10,8 @@ except ModuleNotFoundError:        # property tests skip without hypothesis
     from conftest import given, settings, strategies as st
 
 from repro.core import baselines, packing, scores, slab, sparsity
-from repro.core.apply import slab_linear, slab_linear_packed
+from repro.core.apply import slab_linear
+from repro.core.packed_model import pack_linear, packed_matmul
 from repro.core.slab import SLaBConfig
 
 
@@ -194,7 +195,7 @@ def test_forward_equivalence_paths():
     dec = slab.slab_decompose(w, an, SLaBConfig(cr=0.5, iters=4))
     dense = x @ slab.reconstruct(dec).T
     y1 = slab_linear(x, dec)
-    y2 = slab_linear_packed(x, packing.pack_decomposition(dec))
+    y2 = packed_matmul(x, pack_linear(dec, None), interpret=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(dense),
                                atol=2e-4)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(dense),

@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 import numpy as np
 
 from repro import configs
-from repro.core.packed_model import PackedLinear, _kernel_blocks
+from repro.core.packed_model import (ExpertPackedStack, PackedLinear,
+                                     _kernel_blocks, expert_matmul)
 from repro.kernels import ops
 from repro.kernels.ell import ell_matmul
 from repro.kernels.flash_decode import flash_decode_paged
@@ -170,6 +171,31 @@ def test_slab_nm_matmul_compiles(one_chip, d_in, d_out):
         (planes[0], jnp.bfloat16), (planes[0], jnp.int8),
         (planes[1], jnp.uint32), ((d_out, 1), jnp.bfloat16),
         ((d_in, 1), jnp.bfloat16))
+
+
+def test_expert_matmul_slab_nm_compiles(one_chip):
+    """The expert path is the 2:4 SLaB kernel under ``jax.vmap``: at
+    8 experts of ``ROWS`` tokens each over (D_MODEL, D_FF) planes it is
+    ONE kernel, with the expert index added to its grid, reading x and
+    the stacked planes in place. The only copies are the rank factors'
+    (E, D, 1) -> (E, 1, D) transposes into the kernel's row stacks."""
+    n, m, e = 2, 4, 8
+    planes = ((e, n, D_MODEL // m, D_FF), (e, D_MODEL // 32, D_FF))
+
+    def f(x, vals, idx, bp, u, v):
+        w = PackedLinear(vals, idx, bp, u, v, variant="slab-nm", m_pat=m,
+                         d_in=D_MODEL, d_out=D_FF, rank=1)
+        eps = ExpertPackedStack((w,), None, (tuple(range(e)),), (), e)
+        return expert_matmul(x, eps, interpret=False)
+
+    text = _compile(f, one_chip, ((e, ROWS, D_MODEL), jnp.bfloat16),
+                    (planes[0], jnp.bfloat16), (planes[0], jnp.int8),
+                    (planes[1], jnp.uint32), ((e, D_FF, 1), jnp.bfloat16),
+                    ((e, D_MODEL, 1), jnp.bfloat16)).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    copied = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert set(copied) <= {f"bf16[{e},1,{D_FF}]", f"bf16[{e},1,{D_MODEL}]"}, \
+        copied
 
 
 @pytest.mark.xfail(strict=True, reason=(

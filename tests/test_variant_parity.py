@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 from repro.core.apply import slab_linear
-from repro.core.packed_model import (PACKED_VARIANTS, pack_linear,
+from repro.core.packed_model import (PACKED_VARIANTS, ExpertPackedStack,
+                                     expert_matmul, pack_linear,
                                      packed_matmul, variant_of)
+from repro.core.packing import ell_row_nnz_max
 from repro.core.slab import SLaBDecomposition
 from repro.core.sparsity import prune_mask
 from repro.kernels import ref
@@ -130,3 +132,33 @@ def test_stacked_slice_preserves_variant(variant, rank, pattern):
             np.asarray(packed_matmul(x, sl, interpret=True)),
             np.asarray(packed_matmul(x, pl, interpret=True)),
             rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("variant", PACKED_VARIANTS)
+def test_expert_matmul_matches_per_expert_loop(variant):
+    """Three same-variant experts stacked on a leading expert dim:
+    ``expert_matmul`` (the per-linear dispatch under ``jax.vmap``)
+    equals a loop of ``packed_matmul`` over the experts, and each
+    expert's ref oracle."""
+    rank = 3 if variant in _HAS_LOWRANK else 0
+    pattern = "2:4" if variant.endswith("-nm") else None
+    decs = [_dec(s, variant, rank, pattern) for s in (21, 22, 23)]
+    # ELL experts stack at one shared pad width, as pack_expert_stack
+    # pads a bucket to its realized max
+    nnz = (max(ell_row_nnz_max(d.w_s) for d in decs)
+           if variant.endswith("-ell") else None)
+    pls = [pack_linear(d, pattern, variant=variant, ell_nnz=nnz)
+           for d in decs]
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *pls)
+    eps = ExpertPackedStack((stacked,), None, ((0, 1, 2),), (), 3)
+    x = jax.random.normal(jax.random.PRNGKey(24), (3, 8, K), jnp.float32)
+    got = expert_matmul(x, eps, interpret=True)
+    assert got.shape == (3, 8, N)
+    for e, pl in enumerate(pls):
+        np.testing.assert_allclose(
+            np.asarray(got[e]),
+            np.asarray(packed_matmul(x[e], pl, interpret=True)),
+            rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got[e]),
+                                   np.asarray(_ref_oracle(x[e], pl)),
+                                   rtol=1e-4, atol=1e-4)
